@@ -13,6 +13,8 @@ class Chip:
     hbm_bytes: float
     link_bw: float  # bytes/s per ICI/NVLink-class link
     tdp_watts: float
+    # jax ``Device.device_kind`` strings this row stands for
+    device_kinds: tuple[str, ...] = ()
 
 
 TPU_V5E = Chip(
@@ -22,6 +24,7 @@ TPU_V5E = Chip(
     hbm_bytes=16 * 2 ** 30,
     link_bw=50e9,
     tdp_watts=200.0,
+    device_kinds=("TPU v5 lite", "TPU v5e"),
 )
 
 # Survey Fig. 4 comparison points (nominal public specs)

@@ -25,6 +25,7 @@ NEG_INF = -1e30
 
 def _decode_kernel(nvalid_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, block_kv: int, scale: float):
+    bi = pl.program_id(0)
     ki = pl.program_id(1)
     nk = pl.num_programs(1)
     sq = q_ref.shape[1]
@@ -43,7 +44,7 @@ def _decode_kernel(nvalid_ref, q_ref, k_ref, v_ref, o_ref,
     slot = ki * block_kv + jax.lax.broadcasted_iota(jnp.int32, (sq, block_kv), 1)
     # per-query valid slot count: row i sees n_valid - (sq - 1) + i slots
     row = jax.lax.broadcasted_iota(jnp.int32, (sq, block_kv), 0)
-    limit = nvalid_ref[0] - (sq - 1) + row
+    limit = nvalid_ref[bi] - (sq - 1) + row
     s = jnp.where(slot < limit, s, NEG_INF)
 
     m_prev = m_scr[...]
@@ -138,8 +139,8 @@ def _paged_decode_kernel_int8(table_ref, nvalid_ref, q_ref, k_ref, v_ref,
     def _compute():
         q = q_ref[0, 0].astype(F32)  # (rows, d)
         # inline dequant in VMEM: int8 page values * per-slot fp32 scale
-        k = k_ref[0, 0].astype(F32) * ks_ref[0, 0][:, None]  # (ps, d)
-        v = v_ref[0, 0].astype(F32) * vs_ref[0, 0][:, None]
+        k = k_ref[0, 0].astype(F32) * ks_ref[0, 0]  # (ps, d) * (ps, 1)
+        v = v_ref[0, 0].astype(F32) * vs_ref[0, 0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=F32) * scale
         slot = (ki * page_size
@@ -219,8 +220,8 @@ def paged_decode_attention_int8(q, k_pool, v_pool, k_scale, v_scale,
     """Quantized-pool paged decode attention with fused inline dequant.
 
     Same layout contract as ``paged_decode_attention`` except the pools
-    are int8 and each carries a scale pool: k/v_scale (KVH, P, ps) fp32 —
-    one symmetric scale per (slot, kv-head) vector, living in pages
+    are int8 and each carries a scale pool: k/v_scale (KVH, P, ps, 1)
+    fp32 — one symmetric scale per (slot, kv-head) vector, living in pages
     addressed by the SAME page ids, so the scalar-prefetched table
     resolves both the value page and its scale page in the BlockSpec
     index maps. Dequantization happens in VMEM right before the QK/PV
@@ -233,7 +234,9 @@ def paged_decode_attention_int8(q, k_pool, v_pool, k_scale, v_scale,
     kernel = functools.partial(_paged_decode_kernel_int8, page_size=ps,
                                s_q=s_q, scale=scale)
     page_map = lambda bi, hi, ji, t, nv: (hi, t[bi * n_pages + ji], 0, 0)
-    scale_map = lambda bi, hi, ji, t, nv: (hi, t[bi * n_pages + ji], 0)
+    # the trailing singleton keeps the scale block's last two dims equal
+    # to the array's, as the TPU lowering requires of a (ps,)-wide block
+    scale_map = lambda bi, hi, ji, t, nv: (hi, t[bi * n_pages + ji], 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hkv, n_pages),
@@ -242,8 +245,8 @@ def paged_decode_attention_int8(q, k_pool, v_pool, k_scale, v_scale,
                          lambda bi, hi, ji, t, nv: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, ps, d), page_map),
             pl.BlockSpec((1, 1, ps, d), page_map),
-            pl.BlockSpec((1, 1, ps), scale_map),
-            pl.BlockSpec((1, 1, ps), scale_map),
+            pl.BlockSpec((1, 1, ps, 1), scale_map),
+            pl.BlockSpec((1, 1, ps, 1), scale_map),
         ],
         out_specs=pl.BlockSpec((1, 1, rows, d),
                                lambda bi, hi, ji, t, nv: (bi, hi, 0, 0)),
@@ -274,22 +277,26 @@ def decode_attention(q, k, v, n_valid, *, block_kv: int = 256,
     scale = d ** -0.5
     grid = (bh, w // block_kv)
     kernel = functools.partial(_decode_kernel, block_kv=block_kv, scale=scale)
-    return pl.pallas_call(
-        kernel,
+    # n_valid is a scalar-prefetch operand (whole array in SMEM): the TPU
+    # lowering refuses a (1,)-block over a rank-1 array
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,),
-                         memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
+            pl.BlockSpec((1, sq, d), lambda b, j, nv: (b, 0, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, j, nv: (b, j, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, j, nv: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, sq, d), lambda b, j: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, sq, d), lambda b, j, nv: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((sq,), F32),
             pltpu.VMEM((sq,), F32),
             pltpu.VMEM((sq, d), F32),
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
     )(n_valid, q, k, v)

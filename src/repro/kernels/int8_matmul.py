@@ -32,7 +32,7 @@ def _int8_mm_kernel(x_ref, w_ref, scale_ref, o_ref, acc_scr):
 
     @pl.when(ki == nk - 1)
     def _final():
-        o_ref[...] = (acc_scr[...] * scale_ref[...][None, :]).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] * scale_ref[...]).astype(o_ref.dtype)
 
 
 def int8_matmul(x, w_q, scales, *, block_m: int = 128, block_n: int = 128,
@@ -53,13 +53,15 @@ def int8_matmul(x, w_q, scales, *, block_m: int = 128, block_n: int = 128,
         in_specs=[
             pl.BlockSpec((block_m, block_k), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((block_k, block_n), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((block_n,), lambda i, j, kk: (j,)),
+            # scales ride as a (1, N) row: a rank-1 (block_n,) block gets a
+            # different tiled layout from XLA than the TPU kernel expects
+            pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), F32)],
         interpret=interpret,
-    )(x, w_q, scales)
+    )(x, w_q, scales.reshape(1, n))
 
 
 def quantize_int8(w, axis: int = 0):

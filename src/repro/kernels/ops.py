@@ -106,8 +106,8 @@ def paged_decode_attention_int8(q, k_pool, v_pool, k_scale, v_scale,
           .reshape(b, hkv, g * sq, d))
     kf = k_pool.transpose(2, 0, 1, 3)  # (KVH, P, ps, D)
     vf = v_pool.transpose(2, 0, 1, 3)
-    ksf = k_scale[..., 0].transpose(2, 0, 1)  # (KVH, P, ps)
-    vsf = v_scale[..., 0].transpose(2, 0, 1)
+    ksf = k_scale.transpose(2, 0, 1, 3)  # (KVH, P, ps, 1)
+    vsf = v_scale.transpose(2, 0, 1, 3)
     nv = jnp.minimum(pos, n_pages * ps).astype(jnp.int32)
     o = _da.paged_decode_attention_int8(
         qf, kf, vf, ksf, vsf, page_table.reshape(-1).astype(jnp.int32), nv,

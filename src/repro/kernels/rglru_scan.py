@@ -20,25 +20,30 @@ from jax.experimental.pallas import tpu as pltpu
 F32 = jnp.float32
 
 
-def _rglru_kernel(a_ref, x_ref, h0_ref, y_ref, hT_ref, h_scr, *,
-                  block_t: int):
+def _rglru_kernel(a_ref, x_ref, h0_ref, y_ref, hT_ref, h_scr, a_scr, x_scr,
+                  y_scr, *, block_t: int):
     ti = pl.program_id(2)
     nt = pl.num_programs(2)
 
     @pl.when(ti == 0)
     def _init():
-        h_scr[...] = h0_ref[0].astype(F32)
+        h_scr[...] = h0_ref[0].astype(F32)  # (1, block_l)
 
-    a = a_ref[0].astype(F32)  # (block_t, block_l)
-    x = x_ref[0].astype(F32)
+    # one (1, block_l) row per step, read and written through f32 VMEM
+    # scratch: the TPU lowering has no dynamic slice of a loaded value, and
+    # a dynamic single-row access must not split a packed (bf16) sublane
+    a_scr[...] = a_ref[0].astype(F32)
+    x_scr[...] = x_ref[0].astype(F32)
 
     def step(t, h):
-        h_new = a[t] * h + x[t]
-        y_ref[0, t] = h_new.astype(y_ref.dtype)
+        row = pl.ds(t, 1)
+        h_new = a_scr[row, :] * h + x_scr[row, :]
+        y_scr[row, :] = h_new
         return h_new
 
     h = jax.lax.fori_loop(0, block_t, step, h_scr[...])
     h_scr[...] = h
+    y_ref[0] = y_scr[...].astype(y_ref.dtype)
 
     @pl.when(ti == nt - 1)
     def _final():
@@ -62,17 +67,22 @@ def rglru_scan_kernel(a, x, h0, *, block_l: int = 128, block_t: int = 128,
         in_specs=[
             pl.BlockSpec((1, block_t, block_l), lambda b_, c, t: (b_, t, c)),
             pl.BlockSpec((1, block_t, block_l), lambda b_, c, t: (b_, t, c)),
-            pl.BlockSpec((1, block_l), lambda b_, c, t: (b_, c)),
+            # h0/hT ride as (B, 1, L) so each block's last two dims are
+            # (1, block_l) of a (1, L) array, as the TPU lowering requires
+            pl.BlockSpec((1, 1, block_l), lambda b_, c, t: (b_, 0, c)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_t, block_l), lambda b_, c, t: (b_, t, c)),
-            pl.BlockSpec((1, block_l), lambda b_, c, t: (b_, c)),
+            pl.BlockSpec((1, 1, block_l), lambda b_, c, t: (b_, 0, c)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct(a.shape, a.dtype),
-            jax.ShapeDtypeStruct(h0.shape, h0.dtype),
+            jax.ShapeDtypeStruct((b, 1, l), h0.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((block_l,), F32)],
+        scratch_shapes=[pltpu.VMEM((1, block_l), F32),
+                        pltpu.VMEM((block_t, block_l), F32),
+                        pltpu.VMEM((block_t, block_l), F32),
+                        pltpu.VMEM((block_t, block_l), F32)],
         interpret=interpret,
-    )(a, x, h0)
-    return y, hT
+    )(a, x, h0.reshape(b, 1, l))
+    return y, hT.reshape(b, l)

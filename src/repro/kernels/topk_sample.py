@@ -29,16 +29,17 @@ NEG_INF = -1e30
 
 
 def _topk_sample_kernel(k_ref, temp_ref, x_ref, u_ref, o_ref):
-    v = x_ref.shape[1]
+    i = pl.program_id(0)
+    v = x_ref.shape[2]
     # temperature scale (+0.0 canonicalizes -0.0 for the bit mapping)
-    x = x_ref[...].astype(F32) / temp_ref[0] + 0.0  # (1, V)
+    x = x_ref[0].astype(F32) / temp_ref[i] + 0.0  # (1, V)
     # order-isomorphic uint32 image of float32: descending float order ==
     # descending unsigned order (sign bit flipped for positives, all bits
     # inverted for negatives)
     bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
     mapped = jnp.where(bits >> 31 == 0,
                        bits | jnp.uint32(0x80000000), ~bits)
-    k = k_ref[0]
+    k = k_ref[i]
 
     # radix select: build the k-th largest mapped value MSB-first; a bit
     # stays set iff at least k elements still reach the candidate prefix
@@ -53,11 +54,12 @@ def _topk_sample_kernel(k_ref, temp_ref, x_ref, u_ref, o_ref):
 
     # Gumbel argmax over the surviving entries == one categorical draw
     # from their softmax (temperature already applied)
-    u = jnp.maximum(u_ref[...].astype(F32), 1e-12)
+    u = jnp.maximum(u_ref[0].astype(F32), 1e-12)
     z = jnp.where(keep, x - jnp.log(-jnp.log(u)), NEG_INF)
     m = jnp.max(z)
     idx = jax.lax.broadcasted_iota(jnp.int32, (1, v), 1)
-    o_ref[0, 0] = jnp.min(jnp.where(z == m, idx, v))
+    o_ref[...] = jnp.full(o_ref.shape, jnp.min(jnp.where(z == m, idx, v)),
+                          jnp.int32)
 
 
 def topk_sample(logits, k, temperature, uniform, *, interpret: bool = False):
@@ -65,16 +67,22 @@ def topk_sample(logits, k, temperature, uniform, *, interpret: bool = False):
     uniform (B, V) in [0, 1). Returns (B,) int32 — one token per row drawn
     from the temperature-scaled, top-k-restricted softmax."""
     b, v = logits.shape
-    return pl.pallas_call(
-        _topk_sample_kernel,
+    # k and temperature are scalar-prefetch operands (whole arrays in SMEM);
+    # rows ride as (B, 1, V) so each block's last two dims equal the
+    # array's — the TPU lowering refuses a (1, V) block of a (B, V) array
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[
-            pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1,), lambda i: (i,), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, v), lambda i: (i, 0)),
-            pl.BlockSpec((1, v), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, v), lambda i, k_, t_: (i, 0, 0)),
+            pl.BlockSpec((1, 1, v), lambda i, k_, t_: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda i, k_, t_: (i, 0, 0)),
+    )
+    return pl.pallas_call(
+        _topk_sample_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, 1), jnp.int32),
         interpret=interpret,
-    )(k.astype(jnp.int32), temperature.astype(F32), logits, uniform)[:, 0]
+    )(k.astype(jnp.int32), temperature.astype(F32),
+      logits.reshape(b, 1, v), uniform.reshape(b, 1, v))[:, 0, 0]

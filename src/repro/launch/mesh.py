@@ -7,21 +7,13 @@ as (data=16, model=16); multi-pod = 512 chips as (pod=2, data=16, model=16).
 from __future__ import annotations
 
 import jax
-
-
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """``axis_types`` kwarg when this jax exposes ``sharding.AxisType``
-    (explicit Auto on newer jax; older releases default to the same)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(*, data: int = 1, model: int = 1):
@@ -39,7 +31,7 @@ def make_local_mesh(*, data: int = 1, model: int = 1):
             f"the environment before jax initializes, or shrink the "
             f"requested topology")
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_serving_mesh(topology):

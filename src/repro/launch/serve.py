@@ -69,6 +69,7 @@ from repro.serving import (
     ServingEngine,
     TenantClass,
 )
+from repro.launch.runtime import device_report, use_compile_cache
 from repro.serving.trace_export import request_traces, write_chrome_trace
 
 
@@ -218,6 +219,7 @@ def main():
                          "XLA trace lands in this dir (TensorBoard)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    print(device_report(use_compile_cache()), flush=True)
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -323,3 +323,42 @@ def test_recurrent_arch_falls_back_to_exact_prefill(granite):
         t += 1.0
         eng.step(t)
     assert len(req.output) == 5
+
+
+def test_bfloat16_engine_serves_deterministically():
+    """The dtype every published config declares: a bfloat16 model served
+    from bfloat16 KV pages through bucketed and chunked prefill and a fused
+    decode window, one request sampled, finishes every request with its
+    full token count and reproduces every stream on a fresh engine."""
+    import dataclasses
+
+    from repro.serving import Request, RequestState, SamplingParams
+
+    cfg = dataclasses.replace(get_config("granite-8b").reduced(),
+                              dtype="bfloat16")
+    params = init_params(cfg, jax.random.key(0))
+    assert all(x.dtype == jnp.bfloat16 for x in jax.tree.leaves(params))
+
+    def serve():
+        eng = make_engine(cfg, params, slots=3, paged=True, sync_every=4,
+                          chunk_prefill=32)
+        assert eng.cache["body"][0]["k"].dtype == jnp.bfloat16
+        reqs = [Request(0, _prompt(12, seed=1), 9),
+                Request(1, _prompt(70, seed=2), 9),  # chunked prefill
+                Request(2, _prompt(20, seed=3), 9,
+                        sampling=SamplingParams(temperature=0.8, top_k=20,
+                                                top_p=0.9, seed=7))]
+        for r in reqs:
+            assert eng.submit(r, 0.0)
+        t = 0.0
+        while not all(r.done for r in reqs):
+            t += 1.0
+            eng.step(t)
+        eng.drain(t)
+        assert eng.metrics.prefill_chunks > 0
+        assert any(k.startswith("decode/scan") for k in eng.compile_events)
+        assert all(r.state is RequestState.FINISHED and len(r.output) == 9
+                   for r in reqs)
+        return [list(r.output) for r in reqs]
+
+    assert serve() == serve()
