@@ -1,0 +1,562 @@
+"""Run one benchmark cell: set up, warm up, measure, check, report.
+
+A cell is an entry of ``BENCHMARK.json``'s ``workloads``. Everything it
+names is data found by name: ``configs/<config>.json`` (model and engine),
+``traffic/<traffic>.json`` (the mix), ``cells/<cell>.json`` (the cell's
+own rate or clients, and the limit of its correctness check), and
+``metrics/<metric>.py`` (one reader per per-layer metric).
+
+The window drives the user's entry: ``ServingEngine.submit`` / ``step``
+from an ``EngineConfig``, with ``now`` the wall-clock seconds since the
+window opened. Every timestamp is ``time.perf_counter()`` taken when the
+engine call that delivered the token returns.
+"""
+from __future__ import annotations
+
+import contextlib
+import dataclasses
+import gc
+import importlib.util
+import json
+import math
+import os
+import sys
+import tempfile
+import time
+from typing import List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+BENCH = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(BENCH)
+sys.path[:0] = [os.path.join(ROOT, "src"), BENCH,
+                os.path.join(BENCH, "metrics")]
+
+import flops  # noqa: E402
+import traffic  # noqa: E402
+from weights import published, seed_key, shapes, to_program  # noqa: E402
+
+#: request states after which the engine does no more work on it
+TERMINAL = ("finished", "cancelled", "timed_out", "failed", "shed")
+
+
+def _json(path: str) -> dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+@dataclasses.dataclass
+class Cell:
+    name: str
+    chips: int
+    conf: dict  # configuration file
+    mix: dict  # traffic file
+    load: dict  # the cell's own file
+    per_layer: list  # BENCHMARK.json per_layer entries this cell reports
+    end_to_end: list
+
+
+def find_cell(name: str, root: str = ROOT) -> Cell:
+    """The cell ``name`` of ``<root>/BENCHMARK.json`` with every file it
+    names loaded."""
+    bench = os.path.join(root, "bench")
+    bm = _json(os.path.join(root, "BENCHMARK.json"))
+    cells = {w["name"]: w for w in bm["workloads"]}
+    if name not in cells:
+        raise SystemExit(f"unknown workload {name!r}; BENCHMARK.json has "
+                         f"{sorted(cells)}")
+    w = cells[name]
+    conf_file = {c["name"]: c["file"] for c in bm["configs"]}[w["config"]]
+
+    def here(m):
+        return name in m.get("workloads", [name])
+
+    e2e = [m for m in bm["end_to_end"] if here(m)]
+    moves = {m["name"] for m in e2e}
+    per_layer = [m for m in bm["per_layer"]
+                 if here(m) and m["moves"] in moves]
+    return Cell(name, w["chips"], _json(os.path.join(root, conf_file)),
+                _json(os.path.join(bench, "traffic", w["traffic"] + ".json")),
+                _json(os.path.join(bench, "cells", name + ".json")),
+                per_layer, e2e)
+
+
+def reader(metric: str, bench: str = BENCH):
+    """``read`` of the per-layer metric's own file."""
+    path = os.path.join(bench, "metrics", metric + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "metric_" + metric.replace(".", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+# ---------------------------------------------------------------------------
+# set-up
+# ---------------------------------------------------------------------------
+
+
+def use_cache():
+    """JAX's persistent compilation cache at a fixed path in the checkout,
+    whatever the environment says, with every program cached."""
+    jax.config.update("jax_compilation_cache_dir",
+                      os.path.join(ROOT, ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
+class CompileCount:
+    """Compile requests seen so far in this process, persistent-cache hits
+    and misses alike."""
+
+    def __init__(self):
+        self.n = 0
+        jax.monitoring.register_event_listener(self._on)
+
+    def _on(self, event, **_):
+        if event == "/jax/compilation_cache/compile_requests_use_cache":
+            self.n += 1
+
+
+def program_config(conf: dict):
+    """The program's ArchConfig for a configuration file, checked against
+    the file's published widths."""
+    from repro.configs import get_config
+
+    p = conf["program"]
+    cfg = dataclasses.replace(get_config(p["arch"]), **p.get("overrides", {}))
+    s = shapes(conf)
+    got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
+           cfg.resolved_head_dim, cfg.d_ff, cfg.vocab_size, cfg.rope_theta,
+           cfg.rope_variant, cfg.tie_embeddings, cfg.arch_type)
+    want = (s.layers, s.d, s.heads, s.kv_heads, s.head_dim, s.ff, s.vocab,
+            s.rope_theta, "half" if s.family == "chatglm" else "standard",
+            s.tied, "dense")
+    if got != want:
+        raise SystemExit(f"program config {p} does not match the file's "
+                         f"shapes: {got} != {want}")
+    return cfg, s
+
+
+def build(conf: dict, seed: int, trace: bool):
+    """Weights on the device from the seed in one jitted call, in the
+    program's layout, and the engine.
+
+    An engine ``precision`` of int8 weights (the program's own quantized
+    path) has the program's ``quantize_weights`` run inside that same
+    call, so that no float32 copy of a stacked matrix is made on the
+    device, and hands the engine the weights already quantized."""
+    from repro.models import param_specs, quantize_weights
+    from repro.serving import EngineConfig, PrecisionConfig, ServingEngine
+
+    cfg, s = program_config(conf)
+    e = dict(conf["engine"])
+    prec = PrecisionConfig(**e.pop("precision", {}))
+    int8_weights = prec.quantized_weights
+
+    def init(k):
+        p = to_program(s, published(s, k))
+        return quantize_weights(cfg, p) if int8_weights else p
+
+    params = jax.jit(init)(seed_key(seed))
+    if not int8_weights:
+        want = jax.tree.map(lambda x: (x.shape, x.dtype), param_specs(cfg))
+        got = jax.tree.map(lambda x: (x.shape, x.dtype), params)
+        if got != want:
+            raise SystemExit("weights do not match the program's parameter "
+                             "tree")
+    prec = dataclasses.replace(prec, weight_dtype="")
+    engine = ServingEngine(cfg, params, EngineConfig(
+        **e, precision=prec, tracing=trace))
+    return engine, s
+
+
+# ---------------------------------------------------------------------------
+# the window
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class Rec:
+    spec: traffic.Req
+    req: object  # repro.serving.Request
+    first: Optional[float] = None
+    end: Optional[float] = None
+    seen: int = 0
+
+    @property
+    def done(self) -> bool:
+        return self.req.state.value in TERMINAL
+
+
+class Client:
+    """Submits requests and steps the engine, stamping every token's
+    arrival on the host clock and counting the window's work."""
+
+    def __init__(self, engine, s, seed: int, mix: dict):
+        self.engine, self.s, self.seed, self.mix = engine, s, seed, mix
+        self.live: List[Rec] = []
+        self.recs: List[Rec] = []
+        self.t0 = 0.0
+        self.close = math.inf  # window close, seconds after t0
+        self.work = dict(prefill_flops=0.0, decode_flops=0.0,
+                         decode_bytes=0.0, decode_tokens=0, prompt_tokens=0,
+                         output_tokens=0, ticks=0)
+        self.contexts = 0  # sum of decode contexts in the window
+        self.pages_share: List[float] = []
+        self.annotate = False
+
+    def clock(self) -> float:
+        return time.perf_counter() - self.t0
+
+    def _ann(self, name):
+        if not self.annotate:
+            return contextlib.nullcontext()
+        return jax.profiler.TraceAnnotation(name)
+
+    def submit(self, spec: traffic.Req):
+        from repro.serving import Request, SamplingParams
+
+        sp = SamplingParams()
+        if spec.sampled:
+            sp = SamplingParams(seed=traffic.sample_seed(self.seed, spec.rid),
+                                **self.mix["sampling"])
+        req = Request(rid=spec.rid, prompt=traffic.prompt_tokens(
+            self.seed, spec.rid, spec.prompt_len, self.s.vocab),
+            max_new_tokens=spec.max_new, sampling=sp)
+        rec = Rec(spec, req)
+        self.live.append(rec)
+        self.recs.append(rec)
+        with self._ann("bench.submit"):
+            self.engine.submit(req, self.clock())
+        self._collect()
+        return rec
+
+    def step(self) -> None:
+        with self._ann("bench.step"):
+            self.engine.step(self.clock())
+        self._collect()
+        if self.engine.paged and self.clock() < self.close:
+            a = self.engine.allocator
+            self.pages_share.append(a.pages_in_use / a.capacity)
+
+    def _collect(self):
+        t = self.clock()
+        in_window = t < self.close
+        still = []
+        for rec in self.live:
+            n = len(rec.req.output)
+            if n > rec.seen:
+                if rec.first is None:
+                    rec.first = t
+                    if in_window:
+                        p = rec.spec.prompt_len
+                        self.work["prefill_flops"] += flops.prefill_flops(
+                            self.s, p)
+                        self.work["prompt_tokens"] += p
+                if in_window:
+                    p = rec.spec.prompt_len
+                    self.work["output_tokens"] += n - rec.seen
+                    for k in range(max(rec.seen + 1, 2), n + 1):
+                        c = p + k - 1  # keys the k-th token's query sees
+                        self.work["decode_flops"] += flops.decode_flops(
+                            self.s, c)
+                        self.work["decode_tokens"] += 1
+                        self.contexts += c
+                rec.seen = n
+            if rec.done:
+                rec.end = t
+            else:
+                still.append(rec)
+        self.live = still
+
+
+def warm_up(engine, s, mix: dict, conf: dict, seed: int) -> None:
+    """Serve one request per prefill shape the mix can reach, greedy and
+    (where the mix samples) sampled, to completion: every program the
+    window will run is compiled, or loaded from the cache, here."""
+    from repro.serving import Request, SamplingParams
+
+    e = conf["engine"]
+    lengths = traffic.warm_lengths(mix, e["chunk_prefill"], e["page_size"])
+    n_new = 3 * e.get("sync_every", 8)
+    reqs = []
+    for i, n in enumerate(lengths):
+        sp = SamplingParams()
+        if mix.get("sampled_share", 0) and i % 2:
+            sp = SamplingParams(seed=i, **mix["sampling"])
+        reqs.append(Request(rid=-1 - i, prompt=traffic.prompt_tokens(
+            seed, (1 << 40) + i, n, s.vocab), max_new_tokens=n_new,
+            sampling=sp))
+    t0 = time.perf_counter()
+    for r in reqs:
+        engine.submit(r, time.perf_counter() - t0)
+    while not engine.idle:
+        engine.step(time.perf_counter() - t0)
+    engine.drain(time.perf_counter() - t0)
+    bad = [r.rid for r in reqs if r.state.value != "finished"]
+    if bad:
+        raise SystemExit(f"warm-up requests {bad} did not finish")
+    # the engine stacks 1..sync_every deferred token rows on the host (an
+    # eager concatenate per count); compile each count here, not in the
+    # window
+    row = jnp.zeros((engine.slots,), jnp.int32)
+    for k in range(1, engine.sync_every + 1):
+        jnp.stack([row] * k).block_until_ready()
+    engine.reset()
+
+
+def run_window(d: Client, cell: Cell, seconds: float, on_close,
+               period: Optional[float] = None) -> dict:
+    """Open or closed loop for ``seconds``; an open mix then drains (its
+    arrivals going on) until the window's requests are done or the cap.
+    An open mix's schedule repeats every ``period`` seconds (by default
+    the window's length)."""
+    mix, load = cell.mix, cell.load
+    d.t0 = time.perf_counter()
+    d.close = seconds
+    ticks0 = d.engine.metrics.decode_ticks
+    lates = []
+    idle_s = 0.0  # window seconds in which the engine had nothing to do
+    if mix["kind"] == "open":
+        gen = traffic.open_requests(mix, load, period or seconds)
+        nxt = next(gen)
+        cap = seconds + mix["drain_cap_s"]
+        closed = False
+        while True:
+            now = d.clock()
+            if not closed and now >= seconds:
+                closed = True
+                window_s = now
+                d.work["ticks"] = d.engine.metrics.decode_ticks - ticks0
+                on_close()
+            if closed and (now >= cap or all(
+                    r.done for r in d.recs if r.spec.due < seconds)):
+                break
+            while nxt.due <= now:
+                lates.append(now - nxt.due)
+                d.submit(nxt)
+                nxt = next(gen)
+                now = d.clock()
+            if d.engine.idle:
+                t_idle = d.clock()
+                with d._ann("bench.wait"):
+                    time.sleep(max(0.0, min(nxt.due, seconds if not closed
+                                            else cap) - d.clock()))
+                if not closed:
+                    idle_s += min(d.clock(), seconds) - t_idle
+                continue
+            d.step()
+        window = [r for r in d.recs if r.spec.due < seconds]
+    elif mix["kind"] == "closed":
+        gen = traffic.closed_requests(mix)
+        while True:
+            while len(d.live) < load["clients"]:  # each client's next
+                d.submit(next(gen))
+            d.step()
+            now = d.clock()
+            if now >= seconds:
+                window_s = now
+                d.work["ticks"] = d.engine.metrics.decode_ticks - ticks0
+                on_close()
+                break
+        window = list(d.recs)
+    else:
+        raise ValueError(f"unknown traffic kind {mix['kind']!r}")
+    d.engine.drain(d.clock())
+    d.work["decode_bytes"] = flops.decode_bytes(d.s, d.work["ticks"],
+                                                [d.contexts])
+    return {"window_s": window_s, "window": window, "lates": lates,
+            "end": d.clock(), "idle_s": idle_s}
+
+
+# ---------------------------------------------------------------------------
+# correctness
+# ---------------------------------------------------------------------------
+
+
+#: the number each lane's served tokens are judged by
+NUMBER = {False: "mean_logit_gap", True: "mean_nucleus_gap"}
+
+
+def check(conf: dict, mix: dict, seed: int, sample: List[Rec]):
+    """Per lane, the mean and the widest gap over every served token of
+    the sample: for greedy requests between the reference's best logit
+    and its logit for the served token, for sampled ones between the
+    lowest logit the sampler may draw and the served token's. Returns
+    ``{number: (mean, widest)}`` and the number of tokens scored."""
+    import reference
+
+    s = shapes(conf)
+    sp = mix["sampling"]
+    sampling = (sp["temperature"], sp["top_k"], sp["top_p"])
+    w = jax.jit(lambda k: published(s, k))(seed_key(seed))
+    lanes = {}
+    for rec in sample:
+        g = reference.gaps(s, w, rec.req.prompt, rec.req.output,
+                           sampling)[rec.spec.sampled]
+        lanes.setdefault(rec.spec.sampled, []).append(g)
+    del w
+    gc.collect()
+    out = {}
+    for lane, gs in lanes.items():
+        g = np.concatenate(gs)
+        ok = np.all(np.isfinite(g))
+        out[NUMBER[lane]] = (float(np.mean(g)) if ok else math.inf,
+                             float(np.max(g)) if ok else math.inf)
+    return out, sum(len(g) for gs in lanes.values() for g in gs)
+
+
+def _q(values, q):
+    return float(np.percentile(np.asarray(values, float), q))
+
+
+def peak_bytes(devs) -> Optional[int]:
+    peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs]
+    peaks = [p for p in peaks if p is not None]
+    return max(peaks) if peaks else None
+
+
+def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
+             t_start: float, peak: Optional[dict], log=print) -> dict:
+    devs = jax.devices()[:cell.chips]
+    compiles = CompileCount()
+    engine, s = build(cell.conf, seed, trace)
+    warm_up(engine, s, cell.mix, cell.conf, seed)
+    d = Client(engine, s, seed, cell.mix)
+    prof_dir = None
+    if trace:
+        prof_dir = tempfile.mkdtemp(prefix="bench-trace-")
+        jax.profiler.start_trace(prof_dir)
+        d.annotate = True
+    ann = jax.profiler.TraceAnnotation("bench.window") if trace else None
+    setup_s = time.perf_counter() - t_start
+    log(f"set-up: {setup_s:.4f} s (process start to window open)")
+    c0 = compiles.n
+    if ann:
+        ann.__enter__()
+    out = run_window(d, cell, seconds,
+                     on_close=(lambda: ann.__exit__(None, None, None))
+                     if ann else (lambda: None))
+    in_window = compiles.n - c0
+    win = out["window"]
+    mem = peak_bytes(devs)
+    log(f"window: {out['window_s']:.4f} s, {len(win)} requests attempted, "
+        f"{sum(r.first is not None for r in win)} with a first token, "
+        f"drain ended at {out['end']:.3f} s; compiles inside the window "
+        f"and drain: {in_window}; memory_peak_bytes {mem}")
+    if out["lates"]:
+        log(f"generator lateness: median {_q(out['lates'], 50) * 1e3:.3f} "
+            f"ms, max {max(out['lates']) * 1e3:.3f} ms over "
+            f"{len(out['lates'])} submissions")
+
+    failed = [r for r in win if r.done and r.req.state.value != "finished"
+              or (cell.mix["kind"] == "open" and not r.done)]
+    metrics = {}
+    e2e = {m["name"] for m in cell.end_to_end}
+    if cell.mix["kind"] == "open":
+        ttft = [(r.first if r.first is not None else out["end"])
+                - r.spec.due for r in win]
+        tpot = [(r.end - r.first) / (len(r.req.output) - 1) for r in win
+                if r.req.state.value == "finished" and len(r.req.output) > 1]
+        half = len(ttft) // 2
+        if half:
+            log(f"ttft median, first half of the window's requests "
+                f"{_q(ttft[:half], 50) * 1e3:.1f} ms, second half "
+                f"{_q(ttft[half:], 50) * 1e3:.1f} ms (a growing queue "
+                f"shows as a larger second half)")
+        for name, vals in (("ttft", ttft), ("tpot", tpot)):
+            if vals:
+                log(f"{name}: median {_q(vals, 50) * 1e3:.3f} ms, p75 "
+                    f"{_q(vals, 75) * 1e3:.3f} ms, p90 "
+                    f"{_q(vals, 90) * 1e3:.3f} ms over {len(vals)} requests")
+        if "ttft_p75_ms" in e2e and ttft:
+            metrics["ttft_p75_ms"] = _q(ttft, 75) * 1e3
+        if "tpot_p75_ms" in e2e and tpot:
+            metrics["tpot_p75_ms"] = _q(tpot, 75) * 1e3
+    if "output_tok_per_s" in e2e:
+        metrics["output_tok_per_s"] = (d.work["output_tokens"]
+                                       / out["window_s"])
+    log(f"work in the window: {d.work}")
+    metrics["setup_s"] = setup_s
+    units = {m["name"]: m["unit"] for m in cell.end_to_end}
+    result = {"metrics": {k: {"value": v, "unit": units[k]}
+                          for k, v in metrics.items()}}
+
+    if trace:
+        jax.profiler.stop_trace()
+        d.annotate = False
+        result.update(per_layer(cell, d, out, prof_dir, peak, engine.slots))
+
+    done = {r.spec.rid: r for r in win if r.req.state.value == "finished"}
+    sample = [done[r.rid] for r in traffic.check_sample(
+        [r.spec for r in done.values()], seed, cell.mix["check"])]
+    del engine, d
+    gc.collect()
+    full = all(len(r.req.output) == r.spec.max_new for r in sample)
+    t_check = time.perf_counter()
+    gaps, n = check(cell.conf, cell.mix, seed, sample)
+    log(f"check took {time.perf_counter() - t_check:.1f} s")
+    limits = cell.load["check"]
+    share = cell.mix.get("sampled_share", 0.0)
+    lanes = {NUMBER[False]} if share < 1 else set()
+    lanes |= {NUMBER[True]} if share > 0 else set()
+    correct = (full and set(gaps) == lanes
+               and all(v[0] <= limits[k] for k, v in gaps.items()))
+    log(f"check: {n} served tokens of {len(sample)} requests "
+        f"({sum(r.spec.sampled for r in sample)} sampled; longest "
+        f"{max((r.spec.prompt_len + r.spec.max_new for r in sample), default=0)}"
+        f" tokens) against the float32 reference; all full length: {full}; "
+        f"lanes scored {sorted(gaps)} of {sorted(lanes)}")
+    for k, v in sorted(gaps.items()):
+        log(f"check {k}: widest gap of the lane (not compared) {v[1]!r}")
+    result.update({
+        "correct": correct,
+        "attempted": len(win),
+        "failed": len(failed),
+        "device": {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                   "count": len(devs), "memory_peak_bytes": mem},
+    })
+    if trace:
+        result["device"]["busy_s"] = result.pop("busy_s")
+        result["device"]["window_s"] = result.pop("trace_window_s")
+    result["checks"] = {k: {"value": v[0], "limit": limits[k]}
+                        for k, v in sorted(gaps.items())}
+    return result
+
+
+def per_layer(cell, d: Client, out: dict, prof_dir: str, peak, slots: int):
+    import glob
+    import shutil
+    import types
+
+    import tracereduce
+
+    paths = glob.glob(os.path.join(prof_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    tr = tracereduce.load(paths[0])
+    shutil.rmtree(prof_dir, ignore_errors=True)
+    t0, t1 = tracereduce.window(tr)
+    red = tracereduce.reduce(tr, t0, t1)
+    waits = []
+    for r in out["window"]:
+        t = r.req.trace
+        q = [sp for sp in (t.spans if t else []) if sp.kind == "queued"
+             and sp.t1 is not None]
+        if q:
+            waits.append((q[0].t1 - r.spec.due) * 1e3)
+    run = types.SimpleNamespace(
+        trace=red, window_s=out["window_s"], work=d.work, peak=peak,
+        chips=cell.chips, slots=slots, queue_waits_ms=waits,
+        pages_share=d.pages_share)
+    metrics = {}
+    for m in cell.per_layer:
+        v = reader(m["name"])(run)
+        if v is not None:
+            metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+    return {"metrics": metrics, "busy_s": red["busy_s"],
+            "trace_window_s": red["window_s"],
+            "breakdown": {"device_ops": red["device_ops"],
+                          "idle_gaps": red["idle_gaps"]}}
