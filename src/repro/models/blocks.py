@@ -192,29 +192,37 @@ def _paged_attn_decode(cfg, q, k, v, cache, pages, pos):
     b, s = q.shape[:2]
     ps = cache["k"].shape[1]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-    t = pos_b[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (B, S)
-    phys = jnp.take_along_axis(pages, t // ps, axis=1)  # (B, S)
-    off = t % ps
-    if cache["k"].dtype == jnp.int8:
-        # quantized pools: scatter int8 values AND their per-token scales
-        # at the same (page, offset) addresses — decode-time appends are
-        # always per-token regardless of the prefill scale granularity
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        new_cache = {
-            "k": cache["k"].at[phys, off].set(kq),
-            "v": cache["v"].at[phys, off].set(vq),
-            "k_scale": cache["k_scale"].at[phys, off].set(ks),
-            "v_scale": cache["v_scale"].at[phys, off].set(vs),
-        }
-        out = L.paged_decode_attention_int8(
-            q, new_cache["k"], new_cache["v"], new_cache["k_scale"],
-            new_cache["v_scale"], pages, pos_b + s)
-        return out, new_cache
-    kc = cache["k"].at[phys, off].set(k.astype(cache["k"].dtype))
-    vc = cache["v"].at[phys, off].set(v.astype(cache["v"].dtype))
-    out = L.paged_decode_attention(q, kc, vc, pages, pos_b + s)
-    return out, {"k": kc, "v": vc}
+    with jax.named_scope("attn_kv_write"):
+        t = pos_b[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (B, S)
+        phys = jnp.take_along_axis(pages, t // ps, axis=1)  # (B, S)
+        off = t % ps
+        if cache["k"].dtype == jnp.int8:
+            # quantized pools: scatter int8 values AND their per-token
+            # scales at the same (page, offset) addresses — decode-time
+            # appends are always per-token regardless of the prefill
+            # scale granularity
+            kq, ks = quantize_kv(k)
+            vq, vs = quantize_kv(v)
+            new_cache = {
+                "k": cache["k"].at[phys, off].set(kq),
+                "v": cache["v"].at[phys, off].set(vq),
+                "k_scale": cache["k_scale"].at[phys, off].set(ks),
+                "v_scale": cache["v_scale"].at[phys, off].set(vs),
+            }
+        else:
+            new_cache = {
+                "k": cache["k"].at[phys, off].set(k.astype(cache["k"].dtype)),
+                "v": cache["v"].at[phys, off].set(v.astype(cache["v"].dtype)),
+            }
+    with jax.named_scope("attn_core"):
+        if "k_scale" in new_cache:
+            out = L.paged_decode_attention_int8(
+                q, new_cache["k"], new_cache["v"], new_cache["k_scale"],
+                new_cache["v_scale"], pages, pos_b + s)
+        else:
+            out = L.paged_decode_attention(q, new_cache["k"],
+                                           new_cache["v"], pages, pos_b + s)
+    return out, new_cache
 
 
 def _attn_apply(cfg, p, x, rope_pos, *, mode: str, cache, pos, window: int,
@@ -222,11 +230,12 @@ def _attn_apply(cfg, p, x, rope_pos, *, mode: str, cache, pos, window: int,
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
     h, kv = cfg.num_heads, cfg.num_kv_heads
-    q = L.linear(x, p["wq"], "bsd,de->bse").reshape(b, s, h, hd)
-    k = L.linear(x, p["wk"], "bsd,de->bse").reshape(b, s, kv, hd)
-    v = L.linear(x, p["wv"], "bsd,de->bse").reshape(b, s, kv, hd)
-    q = L.apply_rope(cfg, q, rope_pos)
-    k = L.apply_rope(cfg, k, rope_pos)
+    with jax.named_scope("attn_qkv"):
+        q = L.linear(x, p["wq"], "bsd,de->bse").reshape(b, s, h, hd)
+        k = L.linear(x, p["wk"], "bsd,de->bse").reshape(b, s, kv, hd)
+        v = L.linear(x, p["wv"], "bsd,de->bse").reshape(b, s, kv, hd)
+        q = L.apply_rope(cfg, q, rope_pos)
+        k = L.apply_rope(cfg, k, rope_pos)
 
     quantized = cache is not None and cache["k"].dtype == jnp.int8
 
@@ -242,57 +251,67 @@ def _attn_apply(cfg, p, x, rope_pos, *, mode: str, cache, pos, window: int,
         assert cache is not None
         w = cache["k"].shape[1]
         pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
-        slots = jax.lax.rem(
-            pos_b[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :], w)
-        rows = jnp.arange(b)[:, None]
-        if quantized:
-            kq, ks = quantize_kv(k)
-            vq, vs = quantize_kv(v)
-            new_cache = {
-                "k": cache["k"].at[rows, slots].set(kq),
-                "v": cache["v"].at[rows, slots].set(vq),
-                "k_scale": cache["k_scale"].at[rows, slots].set(ks),
-                "v_scale": cache["v_scale"].at[rows, slots].set(vs),
-            }
-            kc = dequantize_kv(new_cache["k"], new_cache["k_scale"], k.dtype)
-            vc = dequantize_kv(new_cache["v"], new_cache["v_scale"], v.dtype)
-        else:
-            kc = cache["k"].at[rows, slots].set(k)
-            vc = cache["v"].at[rows, slots].set(v)
-            new_cache = {"k": kc, "v": vc}
-        out = L.decode_attention(q, kc, vc, pos_b + s, window=window)
+        with jax.named_scope("attn_kv_write"):
+            slots = jax.lax.rem(
+                pos_b[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :], w)
+            rows = jnp.arange(b)[:, None]
+            if quantized:
+                kq, ks = quantize_kv(k)
+                vq, vs = quantize_kv(v)
+                new_cache = {
+                    "k": cache["k"].at[rows, slots].set(kq),
+                    "v": cache["v"].at[rows, slots].set(vq),
+                    "k_scale": cache["k_scale"].at[rows, slots].set(ks),
+                    "v_scale": cache["v_scale"].at[rows, slots].set(vs),
+                }
+            else:
+                new_cache = {"k": cache["k"].at[rows, slots].set(k),
+                             "v": cache["v"].at[rows, slots].set(v)}
+        with jax.named_scope("attn_core"):
+            if quantized:
+                kc = dequantize_kv(new_cache["k"], new_cache["k_scale"],
+                                   k.dtype)
+                vc = dequantize_kv(new_cache["v"], new_cache["v_scale"],
+                                   v.dtype)
+            else:
+                kc, vc = new_cache["k"], new_cache["v"]
+            out = L.decode_attention(q, kc, vc, pos_b + s, window=window)
     else:
-        out = L.attention(q, k, v, causal=causal, window=window)
+        with jax.named_scope("attn_core"):
+            out = L.attention(q, k, v, causal=causal, window=window)
         if cache is not None:  # prefill: fill the cache with the last W keys
             w = cache["k"].shape[1]
-            k_w, v_w = (k[:, -w:], v[:, -w:]) if s >= w else (k, v)
-            if quantized:
-                from repro.util import hint_val
+            with jax.named_scope("attn_kv_write"):
+                k_w, v_w = (k[:, -w:], v[:, -w:]) if s >= w else (k, v)
+                if quantized:
+                    from repro.util import hint_val
 
-                # single-shot prefill is the one write whose token
-                # positions are guaranteed page-aligned from 0, so the
-                # "page" scale granularity groups here (hint_val is 0 =
-                # per-token otherwise); a truncated window (s > w) starts
-                # mid-page and keeps per-token scales, which only
-                # tightens the error bound
-                group = hint_val("kv_scale_page") if s <= w else 0
-                kq, ks = quantize_kv(k_w, group=group)
-                vq, vs = quantize_kv(v_w, group=group)
-                writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
-            else:
-                writes = {"k": k_w, "v": v_w}
-            if s >= w:
-                new_cache = writes
-            else:
-                new_cache = {
-                    name: jax.lax.dynamic_update_slice_in_dim(
-                        cache[name], val, 0, 1)
-                    for name, val in writes.items()
-                }
+                    # single-shot prefill is the one write whose token
+                    # positions are guaranteed page-aligned from 0, so the
+                    # "page" scale granularity groups here (hint_val is 0 =
+                    # per-token otherwise); a truncated window (s > w) starts
+                    # mid-page and keeps per-token scales, which only
+                    # tightens the error bound
+                    group = hint_val("kv_scale_page") if s <= w else 0
+                    kq, ks = quantize_kv(k_w, group=group)
+                    vq, vs = quantize_kv(v_w, group=group)
+                    writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
+                else:
+                    writes = {"k": k_w, "v": v_w}
+                if s >= w:
+                    new_cache = writes
+                else:
+                    new_cache = {
+                        name: jax.lax.dynamic_update_slice_in_dim(
+                            cache[name], val, 0, 1)
+                        for name, val in writes.items()
+                    }
     out = out.reshape(b, s, h * hd)
     if not project:
         return out, new_cache
-    return L._ar_barrier(L.linear(out, p["wo"], "bse,ed->bsd")), new_cache
+    with jax.named_scope("attn_out"):
+        out = L._ar_barrier(L.linear(out, p["wo"], "bse,ed->bsd"))
+    return out, new_cache
 
 
 def apply_block(cfg, btype: str, p, x, rope_pos, *, mode: str, cache=None,

@@ -159,14 +159,15 @@ def linear(x, w, eq: str):
 
 
 def apply_mlp(cfg, p, x):
-    if cfg.mlp_variant in ("swiglu", "geglu"):
-        act = jax.nn.silu if cfg.mlp_variant == "swiglu" else jax.nn.gelu
-        g = linear(x, p["w_gate"], "...d,df->...f")
-        u = linear(x, p["w_up"], "...d,df->...f")
-        h = act(g) * u
-    else:
-        h = jax.nn.gelu(linear(x, p["w_up"], "...d,df->...f"))
-    return _ar_barrier(linear(h, p["w_down"], "...f,fd->...d"))
+    with jax.named_scope("mlp"):
+        if cfg.mlp_variant in ("swiglu", "geglu"):
+            act = jax.nn.silu if cfg.mlp_variant == "swiglu" else jax.nn.gelu
+            g = linear(x, p["w_gate"], "...d,df->...f")
+            u = linear(x, p["w_up"], "...d,df->...f")
+            h = act(g) * u
+        else:
+            h = jax.nn.gelu(linear(x, p["w_up"], "...d,df->...f"))
+        return _ar_barrier(linear(h, p["w_down"], "...f,fd->...d"))
 
 
 # ---------------------------------------------------------------------------

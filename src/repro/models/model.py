@@ -296,11 +296,13 @@ def forward(cfg, params, batch, *, mode: str = "train",
         new_tail.append(c_new)
         aux = aux + aux_t
 
-    x = L.apply_norm(cfg, params["final_norm"], x)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    logits = jnp.einsum("bsd,dv->bsv", x, head, preferred_element_type=F32)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(cfg, params["final_norm"], x)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed"].T
+        logits = jnp.einsum("bsd,dv->bsv", x, head,
+                            preferred_element_type=F32)
 
     cache_out = None
     if cache is not None:
@@ -350,11 +352,13 @@ def decode_step(cfg, params, cache, batch):
                                   cache=c, pos=pos, pages=pages)
         new_tail.append(c_new)
 
-    x = L.apply_norm(cfg, params["final_norm"], x)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    logits = jnp.einsum("bsd,dv->bsv", x, head, preferred_element_type=F32)
+    with jax.named_scope("lm_head"):
+        x = L.apply_norm(cfg, params["final_norm"], x)
+        head = params.get("lm_head")
+        if head is None:
+            head = params["embed"].T
+        logits = jnp.einsum("bsd,dv->bsv", x, head,
+                            preferred_element_type=F32)
     new_cache = {"body": new_body, "tail": new_tail, "pos": pos + x.shape[1]}
     if pages is not None:
         new_cache["page_table"] = pages

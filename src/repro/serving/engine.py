@@ -375,13 +375,14 @@ def decode_tick(cfg, params, cache, tokens, samp=None, *,
     last = logits[:, -1]
     if logits_sharding is not None:
         last = jax.lax.with_sharding_constraint(last, logits_sharding)
-    if samp is None:
-        nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    else:
-        # the token being drawn lands at absolute position new_pos - 1 +
-        # 1 == the post-step pos: the same fold key the prefill paths use
-        # for the first token (pos = prompt_len), advanced per tick
-        nxt = sample_tokens(last, samp, new_cache["pos"])
+    with jax.named_scope("sampler"):
+        if samp is None:
+            nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
+        else:
+            # the token being drawn lands at absolute position new_pos - 1 +
+            # 1 == the post-step pos: the same fold key the prefill paths use
+            # for the first token (pos = prompt_len), advanced per tick
+            nxt = sample_tokens(last, samp, new_cache["pos"])
     return nxt, new_cache
 
 
@@ -441,6 +442,14 @@ def _token_set(tokens, tok, slot):
 # ---------------------------------------------------------------------------
 # engine helpers
 # ---------------------------------------------------------------------------
+
+
+_NO_PHASE = contextlib.nullcontext()
+
+
+def _no_phase(name: str):
+    """Stand-in for ``jax.profiler.TraceAnnotation`` with tracing off."""
+    return _NO_PHASE
 
 
 def _attn_only(cfg) -> bool:
@@ -717,6 +726,12 @@ class ServingEngine:
         self.compile_events: Dict[str, int] = {}
         self._tick_wall = latency_histogram()  # step() wall s (tracing only)
         self._profiling = False
+        # engine phases on the profiler's clock (tracing only): step() runs
+        # under a StepTraceAnnotation and _step marks each phase, so a
+        # device-idle gap can be put down to the host work over it
+        self._phase = (jax.profiler.TraceAnnotation if self._trace_on
+                       else _no_phase)
+        self._steps = 0  # step() calls (the annotation's step_num)
 
         self._attn_only = _attn_only(cfg)
         self._min_window = _min_cache_window(cfg, window)
@@ -867,7 +882,7 @@ class ServingEngine:
             if self._logits_sharding is not None:
                 logits = jax.lax.with_sharding_constraint(
                     logits, self._logits_sharding)
-            with self._trace_ctx():
+            with self._trace_ctx(), jax.named_scope("sampler"):
                 return sample_tokens(logits, samp1, pos)
 
         donate0 = (0,) if donate else ()
@@ -1548,31 +1563,46 @@ class ServingEngine:
         # step() call — the virtual `now` clock says nothing about what a
         # tick actually cost
         w0 = time.perf_counter()
+        self._steps += 1
         try:
-            return self._step(now)
+            with jax.profiler.StepTraceAnnotation("engine.step",
+                                                  step_num=self._steps):
+                return self._step(now)
         finally:
             self._tick_wall.observe(time.perf_counter() - w0)
 
     def _step(self, now: float) -> List[Request]:
-        self._reap_doomed(now)
-        self._pump_admissions(now)
-        self._run_prefill_chunks(now)
+        phase = self._phase
+        with phase("engine.reap"):
+            self._reap_doomed(now)
+        with phase("engine.admit"):
+            self._pump_admissions(now)
+        with phase("engine.prefill_chunks"):
+            self._run_prefill_chunks(now)
         if not any(self.decoding):
             return self._take_finished()
         if self._fusable():
             if self.paged:
-                self._ensure_headroom(self.sync_every, now)
-            toks, hist, self.cache = self._decode_scan(
-                self.params, self.cache, self._tokens, self._samp)
+                with phase("engine.pages"):
+                    self._ensure_headroom(self.sync_every, now)
+            with phase("engine.dispatch"):
+                toks, hist, self.cache = self._decode_scan(
+                    self.params, self.cache, self._tokens, self._samp)
             self._tokens = toks
             self.metrics.decode_ticks += self.sync_every
+            self.metrics.fused_ticks += self.sync_every
             self._advance_pos(self.sync_every)
-            self._distribute(np.asarray(hist), now)
+            with phase("engine.sync"):
+                hist = np.asarray(hist)
+            with phase("engine.deliver"):
+                self._distribute(hist, now)
             return self._take_finished()
         if self.paged:
-            self._ensure_headroom(1, now)
-        nxt, self.cache = self._decode(self.params, self.cache, self._tokens,
-                                       self._samp)
+            with phase("engine.pages"):
+                self._ensure_headroom(1, now)
+        with phase("engine.dispatch"):
+            nxt, self.cache = self._decode(self.params, self.cache,
+                                           self._tokens, self._samp)
         self._tokens = nxt
         self._unsynced.append(nxt)
         self.metrics.decode_ticks += 1
@@ -1782,9 +1812,11 @@ class ServingEngine:
         stacked (T, B) token block and distributes tokens to requests."""
         if not self._unsynced:
             return
-        toks = np.asarray(jnp.stack(self._unsynced))
+        with self._phase("engine.sync"):
+            toks = np.asarray(jnp.stack(self._unsynced))
         self._unsynced = []
-        self._distribute(toks, now)
+        with self._phase("engine.deliver"):
+            self._distribute(toks, now)
 
     def _distribute(self, toks: np.ndarray, now: float = None):
         """Hand a (T, B) host token block to the per-slot requests."""

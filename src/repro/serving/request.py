@@ -353,6 +353,7 @@ class ServeMetrics:
     tpots: Histogram = field(default_factory=latency_histogram)  # per token
     sla_violations: int = 0
     decode_ticks: int = 0  # batched decode steps executed
+    fused_ticks: int = 0  # ...of them run inside the fused scan window
     host_syncs: int = 0  # device->host token transfers (1 per N ticks)
     prefill_chunks: int = 0  # chunked-prefill pieces interleaved with decode
     # --- shared-prefix KV cache ---
@@ -447,6 +448,7 @@ class ServeMetrics:
         self.tpots.merge(other.tpots)
         self.sla_violations += other.sla_violations
         self.decode_ticks += other.decode_ticks
+        self.fused_ticks += other.fused_ticks
         self.host_syncs += other.host_syncs
         self.prefill_chunks += other.prefill_chunks
         self.prefix_hits += other.prefix_hits
@@ -498,8 +500,9 @@ class ServeMetrics:
         for f in ("completed", "total_tokens", "rejected", "cancelled",
                   "timed_out", "shed", "browned_out", "failed", "preempted",
                   "preempt_restores", "retried", "failed_over",
-                  "decode_ticks", "host_syncs", "prefill_chunks",
-                  "prefix_hits", "prefix_hit_tokens", "sampled_requests",
+                  "decode_ticks", "fused_ticks", "host_syncs",
+                  "prefill_chunks", "prefix_hits", "prefix_hit_tokens",
+                  "sampled_requests",
                   "slo_tracked", "slo_met", "ttft_slo_misses",
                   "tpot_slo_misses"):
             reg.set_counter(f"{prefix}{f}_total", getattr(self, f))
