@@ -180,7 +180,7 @@ def init_paged_block_cache(cfg, btype: str, n_pages: int, page_size: int,
     }
 
 
-def _paged_attn_decode(cfg, q, k, v, cache, pages, pos):
+def _paged_attn_decode(cfg, q, k, v, cache, pages, pos, layer=None):
     """Write the chunk's K/V through the page table and attend.
 
     ``cache`` holds the shared pools (P, ps, kv, hd); ``pages`` is the
@@ -188,10 +188,21 @@ def _paged_attn_decode(cfg, q, k, v, cache, pages, pos):
     ``pages[b, t // ps]`` at offset ``t % ps``. The allocator guarantees
     live slots own disjoint pages, so the batched scatter has no
     cross-slot collisions (freed/inactive slots all alias the reserved
-    trash page 0, whose contents are never attended with weight)."""
+    trash page 0, whose contents are never attended with weight).
+
+    With ``layer`` (the traced index in the layer scan) ``cache`` holds
+    the body's stacked pools (L, P, ps, kv, hd), written and read in
+    place as their (L*P, ps, kv, hd) rows (a bitcast), in which this
+    layer's page p is row ``layer * P + p``: no layer's pool is sliced
+    out of the stack and no copy of the stack is written back."""
     b, s = q.shape[:2]
-    ps = cache["k"].shape[1]
+    n_pool, ps = cache["k"].shape[-4:-2]
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    rows = cache
+    if layer is not None:
+        rows = {name: a.reshape((-1,) + a.shape[2:])
+                for name, a in cache.items()}
+        pages = pages + layer * n_pool
     with jax.named_scope("attn_kv_write"):
         t = pos_b[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # (B, S)
         phys = jnp.take_along_axis(pages, t // ps, axis=1)  # (B, S)
@@ -203,30 +214,26 @@ def _paged_attn_decode(cfg, q, k, v, cache, pages, pos):
             # scale granularity
             kq, ks = quantize_kv(k)
             vq, vs = quantize_kv(v)
-            new_cache = {
-                "k": cache["k"].at[phys, off].set(kq),
-                "v": cache["v"].at[phys, off].set(vq),
-                "k_scale": cache["k_scale"].at[phys, off].set(ks),
-                "v_scale": cache["v_scale"].at[phys, off].set(vs),
-            }
+            writes = {"k": kq, "v": vq, "k_scale": ks, "v_scale": vs}
         else:
-            new_cache = {
-                "k": cache["k"].at[phys, off].set(k.astype(cache["k"].dtype)),
-                "v": cache["v"].at[phys, off].set(v.astype(cache["v"].dtype)),
-            }
+            writes = {"k": k.astype(cache["k"].dtype),
+                      "v": v.astype(cache["v"].dtype)}
+        rows = {name: a.at[phys, off].set(writes[name])
+                for name, a in rows.items()}
     with jax.named_scope("attn_core"):
-        if "k_scale" in new_cache:
+        if "k_scale" in rows:
             out = L.paged_decode_attention_int8(
-                q, new_cache["k"], new_cache["v"], new_cache["k_scale"],
-                new_cache["v_scale"], pages, pos_b + s)
+                q, rows["k"], rows["v"], rows["k_scale"], rows["v_scale"],
+                pages, pos_b + s)
         else:
-            out = L.paged_decode_attention(q, new_cache["k"],
-                                           new_cache["v"], pages, pos_b + s)
-    return out, new_cache
+            out = L.paged_decode_attention(q, rows["k"], rows["v"], pages,
+                                           pos_b + s)
+    return out, {name: a.reshape(cache[name].shape)
+                 for name, a in rows.items()}
 
 
 def _attn_apply(cfg, p, x, rope_pos, *, mode: str, cache, pos, window: int,
-                causal: bool, project: bool = True, pages=None):
+                causal: bool, project: bool = True, pages=None, layer=None):
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
     h, kv = cfg.num_heads, cfg.num_kv_heads
@@ -241,7 +248,8 @@ def _attn_apply(cfg, p, x, rope_pos, *, mode: str, cache, pos, window: int,
 
     new_cache = cache
     if mode == "decode" and pages is not None:
-        out, new_cache = _paged_attn_decode(cfg, q, k, v, cache, pages, pos)
+        out, new_cache = _paged_attn_decode(cfg, q, k, v, cache, pages, pos,
+                                            layer)
     elif mode == "decode":
         # s == 1: one decode step. s > 1: one chunked-prefill chunk — the
         # chunk's keys are written at their rolling slots and the per-query
@@ -315,9 +323,11 @@ def _attn_apply(cfg, p, x, rope_pos, *, mode: str, cache, pos, window: int,
 
 
 def apply_block(cfg, btype: str, p, x, rope_pos, *, mode: str, cache=None,
-                pos=None, pages=None):
+                pos=None, pages=None, layer=None):
     """Returns (x, new_cache, aux_loss). ``pages`` (B, n_pages) switches
-    attention blocks to the paged KV cache (decode mode only)."""
+    attention blocks to the paged KV cache (decode mode only); with
+    ``layer`` (traced) ``cache`` is the scanned body's stacked pools and
+    the block updates its layer's pages in place."""
     from repro.util import hint_opt
 
     aux = jnp.zeros((), F32)
@@ -339,7 +349,7 @@ def apply_block(cfg, btype: str, p, x, rope_pos, *, mode: str, cache=None,
             a_ctx, new_attn_cache = _attn_apply(
                 cfg, p["attn"], h, rope_pos, mode=mode, cache=cache,
                 pos=pos, window=window, causal=causal, project=False,
-                pages=pages)
+                pages=pages, layer=layer)
             h2 = L.apply_norm(cfg, p["norm2"], x)
             if cfg.mlp_variant in ("swiglu", "geglu"):
                 act = jax.nn.silu if cfg.mlp_variant == "swiglu" else jax.nn.gelu
@@ -356,7 +366,7 @@ def apply_block(cfg, btype: str, p, x, rope_pos, *, mode: str, cache=None,
         h = L.apply_norm(cfg, p["norm1"], x)
         a, new_attn_cache = _attn_apply(
             cfg, p["attn"], h, rope_pos, mode=mode, cache=cache, pos=pos,
-            window=window, causal=causal, pages=pages)
+            window=window, causal=causal, pages=pages, layer=layer)
         x = x + a
         h = L.apply_norm(cfg, p["norm2"], x)
         if btype == "moe":

@@ -322,7 +322,13 @@ def decode_step(cfg, params, cache, batch):
     (+ positions for mrope). S=1 is the classic one-token decode step;
     S>1 is a chunked-prefill chunk (attention-block archs only: recurrent
     mixers carry single-step state). Returns (logits (B,S,V), new_cache)
-    with pos advanced by S."""
+    with pos advanced by S.
+
+    A paged cache (one with a ``page_table``) keeps the body's stacked
+    page pools in the layer scan's carry, and each block writes and reads
+    its layer's pages in place. Passed as the scan's xs and ys, each
+    layer's pools would be sliced out of the stacks and the stacks written
+    back and copied, whole, on every call."""
     pattern, n_repeat, tail = block_program(cfg)
     pos = cache["pos"]
     pages = cache.get("page_table")  # paged serving cache (shared pools)
@@ -336,15 +342,29 @@ def decode_step(cfg, params, cache, batch):
         new_cs = []
         for bt, p, c in zip(pattern, p_slices, c_slices):
             x, c_new, aux = apply_block(cfg, bt, p, x, rope_pos,
-                                        mode="decode", cache=c, pos=pos,
-                                        pages=pages)
+                                        mode="decode", cache=c, pos=pos)
             new_cs.append(c_new)
             aux_acc = aux_acc + aux
         return (x, aux_acc), new_cs
 
-    (x, _), new_body = uscan(
-        scan_body, (x, jnp.zeros((), F32)),
-        (params["body"], cache["body"]))
+    def paged_body(carry, p_slices):
+        x, pools, layer = carry
+        new_pools = []
+        for bt, p, pool in zip(pattern, p_slices, pools):
+            x, pool, _ = apply_block(cfg, bt, p, x, rope_pos, mode="decode",
+                                     cache=pool, pos=pos, pages=pages,
+                                     layer=layer)
+            new_pools.append(pool)
+        return (x, new_pools, layer + 1), None
+
+    if pages is None:
+        (x, _), new_body = uscan(
+            scan_body, (x, jnp.zeros((), F32)),
+            (params["body"], cache["body"]))
+    else:
+        (x, new_body, _), _ = uscan(
+            paged_body, (x, cache["body"], jnp.zeros((), jnp.int32)),
+            params["body"])
 
     new_tail = []
     for bt, p, c in zip(tail, params["tail"], cache["tail"]):

@@ -20,6 +20,7 @@ from repro.core.hardware import TPU_V5E
 from repro.kernels import ops
 from repro.models import init_paged_cache, param_specs
 from repro.serving.engine import decode_scan_step, init_sampling_state
+from test_paged_layer_scan import pool_moves
 
 # granite-8b's published attention widths; serving shapes of chip_smoke.py
 B, H, KV, D = 8, 32, 8, 128
@@ -106,3 +107,8 @@ def test_decode_scan_step_compiles_for_v5e(one_chip):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             < TPU_V5E.hbm_bytes)
+    # the layer scan updates the stacked pools in place: no copy of a
+    # stack, and no layer's pool sliced out of it
+    pools = {s for a in jax.tree.leaves(cache["body"])
+             for s in (a.shape, a.shape[1:])}
+    assert pool_moves(compiled.as_text(), pools) == []
