@@ -3,7 +3,8 @@
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``. Everything it
 names is data found by name: ``configs/<config>.json`` (model and engine),
 ``traffic/<traffic>.json`` (the mix), ``cells/<cell>.json`` (the cell's
-own rate or clients, and the limit of its correctness check), and
+own rate or clients, the limit of its correctness check and, where a
+traced run profiles only the window's last seconds, ``trace_seconds``), and
 ``metrics/<metric>.py`` (one reader per per-layer metric).
 
 The window drives the user's entry: ``ServingEngine.submit`` / ``step``
@@ -140,6 +141,55 @@ def program_config(conf: dict):
     return cfg, s
 
 
+def topology(conf: dict):
+    """The program's ``DeviceTopology`` of a configuration's engine: one
+    chip where the file names none."""
+    from repro.serving import DeviceTopology
+
+    return DeviceTopology(**conf["engine"].get("topology", {}))
+
+
+def placement(cfg, topo):
+    """The shardings a sharded engine gives its parameter tree on the mesh
+    it builds for ``topo``, from the program's own functions, so that
+    weights drawn into them are already where the engine keeps them."""
+    from repro.core.simd.sharding import (param_pspecs, serving_policy,
+                                          to_shardings)
+    from repro.launch.mesh import make_serving_mesh
+    from repro.models import param_specs
+
+    mesh = make_serving_mesh(topo)
+    pspecs = param_pspecs(cfg, param_specs(cfg), serving_policy(cfg, mesh))
+    return to_shardings(mesh, pspecs)
+
+
+def weights_program(cfg, s, topo, int8_weights: bool = False):
+    """The jitted call that draws the program's weights from a key: on
+    one chip, whole; over a sharded engine's chips, each leaf straight
+    into the sharding the engine gives it."""
+    from repro.models import quantize_weights
+
+    def init(k):
+        p = to_program(s, published(s, k))
+        return quantize_weights(cfg, p) if int8_weights else p
+
+    if not topo.sharded:
+        return jax.jit(init)
+    out = placement(cfg, topo)
+    attn = out["body"][0]["attn"]
+
+    def init_placed(k):
+        # to_program permutes the columns of wq and wk (a gather): split
+        # them as the engine keeps them first, or GSPMD draws each whole
+        # on every chip
+        w = published(s, k)
+        for name in ("wq", "wk"):
+            w[name] = jax.lax.with_sharding_constraint(w[name], attn[name])
+        return to_program(s, w)
+
+    return jax.jit(init_placed, out_shardings=out)
+
+
 def build(conf: dict, seed: int, trace: bool):
     """Weights on the device from the seed in one jitted call, in the
     program's layout, and the engine.
@@ -147,20 +197,26 @@ def build(conf: dict, seed: int, trace: bool):
     An engine ``precision`` of int8 weights (the program's own quantized
     path) has the program's ``quantize_weights`` run inside that same
     call, so that no float32 copy of a stacked matrix is made on the
-    device, and hands the engine the weights already quantized."""
-    from repro.models import param_specs, quantize_weights
+    device, and hands the engine the weights already quantized.
+
+    Where the engine spans several chips (a ``topology``), the call
+    draws each leaf straight into the sharding the engine gives it: each
+    chip computes only its share, and no chip ever holds the whole
+    model. The draw is partitionable (``jax_threefry_partitionable``),
+    so the values are those of the one-chip draw."""
+    from repro.models import param_specs
     from repro.serving import EngineConfig, PrecisionConfig, ServingEngine
 
     cfg, s = program_config(conf)
     e = dict(conf["engine"])
     prec = PrecisionConfig(**e.pop("precision", {}))
+    topo = topology(conf)
+    e.pop("topology", None)
     int8_weights = prec.quantized_weights
-
-    def init(k):
-        p = to_program(s, published(s, k))
-        return quantize_weights(cfg, p) if int8_weights else p
-
-    params = jax.jit(init)(seed_key(seed))
+    if topo.sharded and int8_weights:
+        raise SystemExit("bench: a sharded engine refuses int8 weights; "
+                         "its control is calibrate.py --precisions int8ref")
+    params = weights_program(cfg, s, topo, int8_weights)(seed_key(seed))
     if not int8_weights:
         want = jax.tree.map(lambda x: (x.shape, x.dtype), param_specs(cfg))
         got = jax.tree.map(lambda x: (x.shape, x.dtype), params)
@@ -169,7 +225,7 @@ def build(conf: dict, seed: int, trace: bool):
                              "tree")
     prec = dataclasses.replace(prec, weight_dtype="")
     engine = ServingEngine(cfg, params, EngineConfig(
-        **e, precision=prec, tracing=trace))
+        **e, topology=topo, precision=prec, tracing=trace))
     return engine, s
 
 
@@ -301,25 +357,47 @@ def warm_up(engine, s, mix: dict, conf: dict, seed: int) -> None:
         raise SystemExit(f"warm-up requests {bad} did not finish")
     # the engine stacks 1..sync_every deferred token rows on the host (an
     # eager concatenate per count); compile each count here, not in the
-    # window
+    # window. A sharded engine's rows are replicated over its mesh, as its
+    # token carry is, and compile apart from one-chip rows
     row = jnp.zeros((engine.slots,), jnp.int32)
+    if engine.mesh is not None:
+        row = jax.device_put(row, engine._tokens.sharding)
     for k in range(1, engine.sync_every + 1):
         jnp.stack([row] * k).block_until_ready()
     engine.reset()
 
 
+def _tally(d: Client, ticks: int) -> dict:
+    """The window's work so far, with ``ticks`` decode ticks."""
+    return dict(d.work, ticks=ticks, decode_bytes=flops.decode_bytes(
+        d.s, ticks, [d.contexts]))
+
+
 def run_window(d: Client, cell: Cell, seconds: float, on_close,
-               period: Optional[float] = None) -> dict:
+               period: Optional[float] = None, on_part=None,
+               part_s: float = 0.0) -> dict:
     """Open or closed loop for ``seconds``; an open mix then drains (its
     arrivals going on) until the window's requests are done or the cap.
     An open mix's schedule repeats every ``period`` seconds (by default
-    the window's length)."""
+    the window's length). With ``on_part``, it is called once between
+    engine steps when ``part_s`` seconds of the window are left, and
+    ``part`` holds the clock and the work done when it returned."""
     mix, load = cell.mix, cell.load
     d.t0 = time.perf_counter()
     d.close = seconds
     ticks0 = d.engine.metrics.decode_ticks
     lates = []
     idle_s = 0.0  # window seconds in which the engine had nothing to do
+    part = None
+    mark = seconds - part_s if on_part else math.inf
+
+    def at_mark():
+        nonlocal part, mark
+        if d.clock() >= mark:
+            mark = math.inf
+            on_part()
+            part = {"t": d.clock(), "pages": len(d.pages_share),
+                    "work": _tally(d, d.engine.metrics.decode_ticks - ticks0)}
     if mix["kind"] == "open":
         gen = traffic.open_requests(mix, load, period or seconds)
         nxt = next(gen)
@@ -328,6 +406,7 @@ def run_window(d: Client, cell: Cell, seconds: float, on_close,
         while True:
             now = d.clock()
             if not closed and now >= seconds:
+                at_mark()
                 closed = True
                 window_s = now
                 d.work["ticks"] = d.engine.metrics.decode_ticks - ticks0
@@ -340,11 +419,12 @@ def run_window(d: Client, cell: Cell, seconds: float, on_close,
                 d.submit(nxt)
                 nxt = next(gen)
                 now = d.clock()
+            at_mark()
             if d.engine.idle:
                 t_idle = d.clock()
                 with d._ann("bench.wait"):
-                    time.sleep(max(0.0, min(nxt.due, seconds if not closed
-                                            else cap) - d.clock()))
+                    time.sleep(max(0.0, min(nxt.due, mark, seconds if not
+                                            closed else cap) - d.clock()))
                 if not closed:
                     idle_s += min(d.clock(), seconds) - t_idle
                 continue
@@ -355,9 +435,11 @@ def run_window(d: Client, cell: Cell, seconds: float, on_close,
         while True:
             while len(d.live) < load["clients"]:  # each client's next
                 d.submit(next(gen))
+            at_mark()
             d.step()
             now = d.clock()
             if now >= seconds:
+                at_mark()
                 window_s = now
                 d.work["ticks"] = d.engine.metrics.decode_ticks - ticks0
                 on_close()
@@ -366,10 +448,9 @@ def run_window(d: Client, cell: Cell, seconds: float, on_close,
     else:
         raise ValueError(f"unknown traffic kind {mix['kind']!r}")
     d.engine.drain(d.clock())
-    d.work["decode_bytes"] = flops.decode_bytes(d.s, d.work["ticks"],
-                                                [d.contexts])
+    d.work = _tally(d, d.work["ticks"])
     return {"window_s": window_s, "window": window, "lates": lates,
-            "end": d.clock(), "idle_s": idle_s}
+            "end": d.clock(), "idle_s": idle_s, "part": part}
 
 
 # ---------------------------------------------------------------------------
@@ -377,28 +458,98 @@ def run_window(d: Client, cell: Cell, seconds: float, on_close,
 # ---------------------------------------------------------------------------
 
 
+def published_shardings(s, topo) -> dict:
+    """Shardings of the published weights over the chips of a sharded
+    engine: every stacked matrix split on its last axis, the embedding
+    on the vocabulary, norms whole on each chip. The reference's einsums
+    are then partitioned by GSPMD, and no chip holds more than its share
+    of the weights, a layer of them in float32 and one sequence's
+    logits."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.launch.mesh import make_serving_mesh
+
+    mesh = make_serving_mesh(topo)
+    last = NamedSharding(mesh, P(None, None, "model"))
+    whole = NamedSharding(mesh, P())
+    out = {k: last for k in ("wq", "wk", "wv", "wo", "w_gate", "w_up",
+                             "w_down")}
+    out.update(embed=NamedSharding(mesh, P("model", None)),
+               attn_norm=whole, mlp_norm=whole, final_norm=whole)
+    if not s.tied:
+        out["lm_head"] = NamedSharding(mesh, P(None, "model"))
+    return out
+
+
+def published_program(s, topo):
+    """The jitted call that draws the published weights from a key for the
+    reference: whole on one chip, and over a sharded engine's chips split
+    by ``published_shardings``."""
+    if not topo.sharded:
+        return jax.jit(lambda k: published(s, k))
+    return jax.jit(lambda k: published(s, k),
+                   out_shardings=published_shardings(s, topo))
+
+
 #: the number each lane's served tokens are judged by
 NUMBER = {False: "mean_logit_gap", True: "mean_nucleus_gap"}
 
 
-def check(conf: dict, mix: dict, seed: int, sample: List[Rec]):
+#: tokens before a served token that make its context in ``look``
+CONTEXT = 8
+
+
+def look(prompt, served, g) -> str:
+    """Where one request's gaps ``g`` come from: how many of its served
+    tokens have a gap, their sum and widest, and at how many distinct
+    contexts (the ``CONTEXT`` tokens before, and the token) they lie,
+    beside the share of distinct contexts among all its served tokens.
+    A greedy continuation that loops repeats its contexts, and a gap at
+    one of them recurs with every turn of the loop."""
+    seq = np.concatenate([np.asarray(prompt), np.asarray(served)])
+    at = len(prompt) + np.arange(len(served))
+    ctx = [tuple(seq[max(i - CONTEXT, 0):i + 1]) for i in at]
+    hit = np.flatnonzero(g > 0)
+    return (f"{len(served)} tokens, distinct contexts "
+            f"{len(set(ctx)) / max(len(ctx), 1):.3f} of them; {len(hit)} "
+            f"with a gap, sum {float(np.sum(g)):.4f}, widest "
+            f"{float(np.max(g, initial=0)):.4f}, at "
+            f"{len({ctx[i] for i in hit})} distinct contexts")
+
+
+def check(conf: dict, mix: dict, seed: int, sample: List[Rec],
+          control: bool = False, log=None):
     """Per lane, the mean and the widest gap over every served token of
     the sample: for greedy requests between the reference's best logit
     and its logit for the served token, for sampled ones between the
     lowest logit the sampler may draw and the served token's. Returns
-    ``{number: (mean, widest)}`` and the number of tokens scored."""
+    ``{number: (mean, widest)}`` and the number of tokens scored. With
+    ``control``, the tokens judged are the control's picks at the same
+    positions (``control.py``), not the served ones. With ``log``, each
+    request's ``look``."""
+    import control as ctl
     import reference
 
     s = shapes(conf)
     sp = mix["sampling"]
     sampling = (sp["temperature"], sp["top_k"], sp["top_p"])
-    w = jax.jit(lambda k: published(s, k))(seed_key(seed))
+    w = published_program(s, topology(conf))(seed_key(seed))
+    w8 = ctl.int8_weights(w) if control else None
     lanes = {}
     for rec in sample:
-        g = reference.gaps(s, w, rec.req.prompt, rec.req.output,
-                           sampling)[rec.spec.sampled]
-        lanes.setdefault(rec.spec.sampled, []).append(g)
-    del w
+        if control:
+            key = jax.random.fold_in(seed_key(seed), rec.spec.rid)
+            g = ctl.gaps(s, w, w8, rec.req.prompt, rec.req.output,
+                         sampling, key)
+        else:
+            g = reference.gaps(s, w, rec.req.prompt, rec.req.output,
+                               sampling)
+        lanes.setdefault(rec.spec.sampled, []).append(g[rec.spec.sampled])
+        if log:
+            log(f"check rid {rec.spec.rid} ({NUMBER[rec.spec.sampled]}"
+                f"{', control' if control else ''}): "
+                + look(rec.req.prompt, rec.req.output, g[rec.spec.sampled]))
+    del w, w8
     gc.collect()
     out = {}
     for lane, gs in lanes.items():
@@ -420,26 +571,46 @@ def peak_bytes(devs) -> Optional[int]:
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
-             t_start: float, peak: Optional[dict], log=print) -> dict:
+             t_start: float, peak: Optional[dict], log=print,
+             control: bool = False) -> dict:
+    """One run of ``cell``. With ``control``, the served tokens are also
+    judged as a benchmark run judges them (``sound_checks``), and
+    ``correct`` and ``checks`` are those of the control (``control.py``)
+    at the same positions."""
     devs = jax.devices()[:cell.chips]
     compiles = CompileCount()
     engine, s = build(cell.conf, seed, trace)
     warm_up(engine, s, cell.mix, cell.conf, seed)
     d = Client(engine, s, seed, cell.mix)
     prof_dir = None
-    if trace:
-        prof_dir = tempfile.mkdtemp(prefix="bench-trace-")
+    # a cell's ``trace_seconds`` profiles only the window's last seconds
+    part_s = cell.load.get("trace_seconds", seconds) if trace else seconds
+    part_s = part_s if part_s < seconds else 0.0
+    ann = []  # the window's annotation, made once the profiler runs
+
+    def open_trace():
         jax.profiler.start_trace(prof_dir)
         d.annotate = True
-    ann = jax.profiler.TraceAnnotation("bench.window") if trace else None
+        ann.append(jax.profiler.TraceAnnotation("bench.window"))
+
+    if trace:
+        prof_dir = tempfile.mkdtemp(prefix="bench-trace-")
+        if not part_s:
+            open_trace()
     setup_s = time.perf_counter() - t_start
     log(f"set-up: {setup_s:.4f} s (process start to window open)")
     c0 = compiles.n
     if ann:
-        ann.__enter__()
+        ann[0].__enter__()
+
+    def on_part():
+        open_trace()
+        ann[0].__enter__()
+
     out = run_window(d, cell, seconds,
-                     on_close=(lambda: ann.__exit__(None, None, None))
-                     if ann else (lambda: None))
+                     on_close=lambda: ann and ann[0].__exit__(None, None,
+                                                              None),
+                     on_part=on_part if part_s else None, part_s=part_s)
     in_window = compiles.n - c0
     win = out["window"]
     mem = peak_bytes(devs)
@@ -486,9 +657,25 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
                           for k, v in metrics.items()}}
 
     if trace:
+        t_trace = time.perf_counter()
         jax.profiler.stop_trace()
         d.annotate = False
-        result.update(per_layer(cell, d, out, prof_dir, peak, engine.slots))
+        t_read = time.perf_counter()
+        part = out["part"]
+        span = {"work": d.work, "window_s": out["window_s"],
+                "pages_share": d.pages_share}
+        if part:
+            span = {"work": {k: v - part["work"][k]
+                             for k, v in d.work.items()},
+                    "window_s": out["window_s"] - part["t"],
+                    "pages_share": d.pages_share[part["pages"]:]}
+            log(f"traced part: opened at {part['t']:.4f} s, "
+                f"{span['window_s']:.4f} s long; its work {span['work']}")
+        result.update(per_layer(cell, span, out["window"], prof_dir, peak,
+                                engine.slots))
+        log(f"trace: stopped and written in {t_read - t_trace:.1f} s, read "
+            f"and reduced in {time.perf_counter() - t_read:.1f} s; devices "
+            f"read {result.pop('devices_read')} of {cell.chips}")
 
     done = {r.spec.rid: r for r in win if r.req.state.value == "finished"}
     sample = [done[r.rid] for r in traffic.check_sample(
@@ -497,9 +684,16 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     gc.collect()
     full = all(len(r.req.output) == r.spec.max_new for r in sample)
     t_check = time.perf_counter()
-    gaps, n = check(cell.conf, cell.mix, seed, sample)
+    gaps, n = check(cell.conf, cell.mix, seed, sample, log=log)
     log(f"check took {time.perf_counter() - t_check:.1f} s")
     limits = cell.load["check"]
+    sound = {}
+    if control:
+        sound = {k: {"value": v[0], "limit": limits[k]}
+                 for k, v in sorted(gaps.items())}
+        log(f"check of the served tokens: {sound}")
+        gaps, n = check(cell.conf, cell.mix, seed, sample, control=True,
+                        log=log)
     share = cell.mix.get("sampled_share", 0.0)
     lanes = {NUMBER[False]} if share < 1 else set()
     lanes |= {NUMBER[True]} if share > 0 else set()
@@ -522,12 +716,19 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     if trace:
         result["device"]["busy_s"] = result.pop("busy_s")
         result["device"]["window_s"] = result.pop("trace_window_s")
+    if control:
+        result["sound_checks"] = sound
     result["checks"] = {k: {"value": v[0], "limit": limits[k]}
                         for k, v in sorted(gaps.items())}
     return result
 
 
-def per_layer(cell, d: Client, out: dict, prof_dir: str, peak, slots: int):
+def per_layer(cell, span: dict, window: List[Rec], prof_dir: str, peak,
+              slots: int):
+    """The cell's per-layer metrics from the profile in ``prof_dir``, with
+    the work, host seconds and page shares of the traced ``span`` (the
+    window, or its traced part) and the queue waits of ``window``'s
+    requests."""
     import glob
     import shutil
     import types
@@ -541,16 +742,16 @@ def per_layer(cell, d: Client, out: dict, prof_dir: str, peak, slots: int):
     t0, t1 = tracereduce.window(tr)
     red = tracereduce.reduce(tr, t0, t1)
     waits = []
-    for r in out["window"]:
+    for r in window:
         t = r.req.trace
         q = [sp for sp in (t.spans if t else []) if sp.kind == "queued"
              and sp.t1 is not None]
         if q:
             waits.append((q[0].t1 - r.spec.due) * 1e3)
     run = types.SimpleNamespace(
-        trace=red, window_s=out["window_s"], work=d.work, peak=peak,
+        trace=red, window_s=span["window_s"], work=span["work"], peak=peak,
         chips=cell.chips, slots=slots, queue_waits_ms=waits,
-        pages_share=d.pages_share)
+        pages_share=span["pages_share"])
     metrics = {}
     for m in cell.per_layer:
         v = reader(m["name"])(run)
@@ -558,5 +759,6 @@ def per_layer(cell, d: Client, out: dict, prof_dir: str, peak, slots: int):
             metrics[m["name"]] = {"value": v, "unit": m["unit"]}
     return {"metrics": metrics, "busy_s": red["busy_s"],
             "trace_window_s": red["window_s"],
+            "devices_read": red["devices_read"],
             "breakdown": {"device_ops": red["device_ops"],
                           "idle_gaps": red["idle_gaps"]}}

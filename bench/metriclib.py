@@ -7,7 +7,9 @@ number or None when the run gave it nothing to read. ``run`` carries:
 ``window_s`` (host seconds the window lasted), ``work`` (model work done
 in the window, from ``flops``), ``peak`` (the chip's row of
 ``peaks.json``), ``chips``, ``slots``, ``queue_waits_ms`` and
-``pages_share``.
+``pages_share``. Where the cell traces only the window's last
+``trace_seconds``, ``window_s``, ``work`` and ``pages_share`` are those
+of that part; ``queue_waits_ms`` stays the window's.
 """
 from __future__ import annotations
 

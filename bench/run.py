@@ -9,8 +9,9 @@ shape the window uses), measures for ``--seconds``, checks the served
 tokens against the plain reference, and prints one JSON object as the
 last line of standard output. ``--trace 0`` reports the cell's
 end-to-end metrics, ``--trace 1`` its per-layer metrics from a profiled
-window. Exits non-zero, with no result, when JAX finds no TPU, fewer
-chips than the cell asks for, or a chip missing from ``peaks.json``.
+window. Exits non-zero, with no result, when the cell's chips are not
+those its engine's topology spans, or JAX finds no TPU, fewer chips than
+the cell asks for, or a chip missing from ``peaks.json``.
 """
 import time
 
@@ -40,6 +41,10 @@ def main(argv=None):
 
     cell = harness.find_cell(args.workload)
     harness.use_cache()
+    n = harness.topology(cell.conf).n_chips
+    if n != cell.chips:
+        raise SystemExit(f"bench: {args.workload} asks for {cell.chips} "
+                         f"chips, its engine's topology spans {n}")
     import jax
 
     devs = jax.devices()

@@ -187,7 +187,7 @@ def load(path: str) -> dict:
     annotations (``engine.*``)."""
     from jax.profiler import ProfileData
 
-    tr = tracereduce.load(path)
+    tr = tracereduce.load(path, every_name=True)
     tr["engine"] = []
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/host:CPU"):
@@ -265,12 +265,14 @@ def owned_ops(dev: dict, t0: float, t1: float) -> List[tuple]:
 
 
 def decode_scope_s(tr: dict, t0: float, t1: float, top: int = 3) -> dict:
-    """Device seconds of the decode programs on the first device by model
-    scope (``unscoped`` included), and the ``top`` longest operations of
-    each, or None where no decode operation carries any scope."""
+    """Device seconds of the decode programs on the first device whose
+    record is whole (``tracereduce.whole_records``) by model scope
+    (``unscoped`` included), and the ``top`` longest operations of each,
+    or None where no decode operation carries any scope."""
     by: Dict[str, float] = defaultdict(float)
     ops: Dict[str, Dict[str, float]] = defaultdict(lambda: defaultdict(float))
-    for owner, name, a, b, path in owned_ops(tr["devices"][0], t0, t1):
+    dev = tracereduce.whole_records(tr, t0, t1)[0]
+    for owner, name, a, b, path in owned_ops(dev, t0, t1):
         if not any(p in owner for p in DECODE_PROGRAMS):
             continue
         scope = scope_of(path)
@@ -285,10 +287,12 @@ def decode_scope_s(tr: dict, t0: float, t1: float, top: int = 3) -> dict:
 
 
 def idle_by_phase(tr: dict, t0: float, t1: float) -> List[list]:
-    """Idle seconds of the first device by the innermost host annotation,
-    benchmark's or engine's, over each gap (all labels, longest first)."""
+    """Idle seconds of the first device whose record is whole by the
+    innermost host annotation, benchmark's or engine's, over each gap (all
+    labels, longest first)."""
     host = {"host": tr["host"] + tr.get("engine", [])}
-    return tracereduce.idle_by_host(host, tr["devices"][0], t0, t1)
+    return tracereduce.idle_by_host(
+        host, tracereduce.whole_records(tr, t0, t1)[0], t0, t1)
 
 
 def readings(split: Optional[dict], idle: Optional[List[list]],
@@ -331,8 +335,9 @@ COUNTERS = ("decode_ticks", "fused_ticks", "host_syncs", "prefill_chunks",
 
 
 def excerpt(tr: dict, t0: float, t1: float) -> dict:
-    """``tr`` cut to [t0, t1): every event that starts inside it, with a
-    ``bench.window`` of that span."""
+    """``tr`` cut to [t0, t1): every event that starts inside it, of the
+    first device whose record is whole, with a ``bench.window`` of that
+    span."""
 
     def cut(evs):
         return [list(e) for e in evs if t0 <= e[1] < t1]
@@ -340,7 +345,8 @@ def excerpt(tr: dict, t0: float, t1: float) -> dict:
     devs = [{"id": d["id"], "modules": cut(d["modules"]),
              "ops": [[tracereduce.op_name(n), s, d_]
                      for n, s, d_ in cut(d["ops"])],
-             "op_scopes": cut(d["op_scopes"])} for d in tr["devices"][:1]]
+             "op_scopes": cut(d["op_scopes"])}
+            for d in tracereduce.whole_records(tr, t0, t1)[:1]]
     host = [list(e) for e in tr["host"]
             if e[0] != "bench.window" and e[1] < t1 and e[1] + e[2] > t0]
     return {"devices": devs, "host": host + [["bench.window", t0, t1 - t0]],
@@ -351,7 +357,8 @@ def excerpt(tr: dict, t0: float, t1: float) -> dict:
 def _decode_excerpt_span(tr: dict, t0: float, t1: float, n: int = 3):
     """[start, end) of ``n`` decode programs back to back from the middle
     of the window."""
-    mods = sorted((e for e in tr["devices"][0]["modules"]
+    dev = tracereduce.whole_records(tr, t0, t1)[0]
+    mods = sorted((e for e in dev["modules"]
                    if t0 <= e[1] and e[1] + e[2] <= t1
                    and any(p in e[0] for p in DECODE_PROGRAMS)),
                   key=lambda e: e[1])

@@ -34,7 +34,7 @@ def _json(*parts):
 
 
 MIXES = ("code-burst", "chat-sat")
-CONFIGS = ("granite-8b-l16", "chatglm3-6b-l20")
+CONFIGS = ("granite-8b-l16", "chatglm3-6b-l20", "granite-8b-tp4")
 SEEDS = (0, 7, 2**31 + 5, 2**33 + 1)
 
 
@@ -91,6 +91,17 @@ def test_closed_pool_is_one_cycle_from_its_start():
     again = traffic.closed_requests(mix)
     assert [(r.prompt_len, r.max_new, r.sampled)
             for r in (next(again) for _ in range(n))] == key[:n]
+
+
+@pytest.mark.parametrize("served,distinct,at", [
+    ([4, 5] * 10, "0.500", 5),  # a loop: the gap at token 5 recurs
+    (list(range(10, 30)), "1.000", 10),
+])
+def test_look_counts_gapped_tokens_and_their_contexts(served, distinct, at):
+    g = np.array([0.0, 0.5] * 10, np.float32)
+    assert harness.look(np.array([1, 2, 3], np.int32), served, g) == (
+        f"20 tokens, distinct contexts {distinct} of them; 10 with a gap, "
+        f"sum 5.0000, widest 0.5000, at {at} distinct contexts")
 
 
 @pytest.mark.parametrize("seed", SEEDS)
@@ -201,6 +212,185 @@ def test_reduction_by_hand():
         {"bench.step": 0.020, "host.other": 0.030})
 
 
+@pytest.mark.parametrize("name", ["trace_excerpt.json",
+                                  "scoped_excerpt.json"])
+def test_reduction_keeps_its_keys_and_values(name):
+    with open(os.path.join(BENCH, "testdata", name)) as f:
+        tr = json.load(f)
+    before = _json("testdata", "reduced_before_collectives.json")[name]
+    red = tracereduce.reduce(tr, *tracereduce.window(tr))
+    assert set(red) == set(before) | {"collective_s", "devices_read"}
+    assert {k: red[k] for k in before} == before
+    # one chip: no collective anywhere
+    assert red["collective_s"] == {}
+
+
+def test_collective_time_by_hand():
+    ms = 1e6
+    tr = {"host": [["bench.window", 0, 100 * ms]],
+          "devices": [{"id": 0,
+                       "modules": [["jit__probed_decode(3)", 0, 60 * ms],
+                                   ["jit__chunk_step(9)", 60 * ms, 40 * ms]],
+                       "ops": [["%while.1", 0, 60 * ms],
+                               ["%fusion.1 fusion bf16[8]", 0, 10 * ms],
+                               ["%all-gather-start.2", 5 * ms, 1 * ms],
+                               ["%fusion.2 fusion bf16[8]", 6 * ms, 10 * ms],
+                               ["%all-gather-done.2 all-gather-done "
+                                "bf16[8,4096]", 16 * ms, 4 * ms],
+                               ["%fusion.3 all-reduce f32[8]", 30 * ms,
+                                10 * ms],
+                               ["%dot.4 dot f32[8]", 35 * ms, 10 * ms],
+                               ["%all-reduce.5 all-reduce f32[8]", 58 * ms,
+                                6 * ms]]}]}
+    red = tracereduce.reduce(tr, *tracereduce.window(tr))
+    # decode: the start (inside fusion.1), the done 16..20 exposed, the
+    # fused all-reduce 30..40 exposed 30..35, the all-reduce 58..64 split
+    # at the program boundary
+    got = {(m, k): v for m, d in red["collective_s"].items()
+           for k, v in d.items()}
+    assert got == pytest.approx({
+        ("_probed_decode", "all"): 0.001 + 0.004 + 0.010 + 0.002,
+        ("_probed_decode", "exposed"): 0.004 + 0.005 + 0.002,
+        ("_chunk_step", "all"): 0.004, ("_chunk_step", "exposed"): 0.004})
+    assert tracereduce.is_collective("%all-reduce-start.3")
+    assert tracereduce.is_collective("%fusion.7 reduce-scatter bf16[8]")
+    assert not tracereduce.is_collective("%fusion.7 fusion bf16[8]")
+    assert not tracereduce.is_collective("%reduce.1 reduce f32[8]")
+
+
+def test_a_device_whose_record_lost_events_is_left_out():
+    ms = 1e6
+    tick = [["%fusion.1 fusion bf16[8]", 0, 4 * ms],
+            ["%all-reduce.2 all-reduce bf16[8]", 4 * ms, 1 * ms],
+            ["%fusion.3 fusion bf16[8]", 5 * ms, 5 * ms]]
+
+    def dev(i, ticks):
+        return {"id": i,
+                "modules": [["jit__probed_decode(1)", k * 20 * ms, 10 * ms]
+                            for k in ticks],
+                "ops": [[n, k * 20 * ms + s, d] for k in ticks
+                        for n, s, d in tick]}
+
+    tr = {"host": [["bench.window", 0, 100 * ms]],
+          "devices": [dev(0, [0, 1, 2]), dev(1, range(5)), dev(2, range(5))]}
+    red = tracereduce.reduce(tr, *tracereduce.window(tr))
+    assert red["devices_read"] == [1, 2]
+    assert red["busy_s"] == pytest.approx(0.050)
+    assert red["module_s"] == pytest.approx({"_probed_decode": 0.050})
+    assert red["collective_s"]["_probed_decode"]["exposed"] == \
+        pytest.approx(0.005)
+
+
+def _xspace(planes) -> bytes:
+    """A profiler ``XSpace`` message: ``planes`` is [(name, {line name:
+    [(event name, start_ns, duration_ns)]})]."""
+    def varint(n):
+        out = bytearray()
+        while True:
+            b, n = n & 0x7F, n >> 7
+            out.append(b | 0x80 if n else b)
+            if not n:
+                return bytes(out)
+
+    def field(num, val):
+        if isinstance(val, int):
+            return varint(num << 3) + varint(val)
+        val = val.encode() if isinstance(val, str) else val
+        return varint(num << 3 | 2) + varint(len(val)) + val
+
+    out = b""
+    for pid, (pname, lines) in enumerate(planes):
+        ids = {}
+        body = field(1, pid) + field(2, pname)
+        for lid, (lname, evs) in enumerate(lines.items()):
+            line = field(1, lid) + field(2, lname) + field(3, 0)
+            for name, s, d in evs:
+                mid = ids.setdefault(name, len(ids) + 1)
+                line += field(4, field(1, mid) + field(2, s * 1000)
+                              + field(3, d * 1000))
+            body += field(3, line)
+        for name, mid in ids.items():
+            body += field(4, field(1, mid) + field(2, field(1, mid)
+                                                   + field(2, name)))
+        out += field(1, body)
+    return out
+
+
+def test_load_names_the_operations_of_the_device_it_reads(tmp_path):
+    tick = [("%fusion.1 = bf16[8]{0} fusion(%a)", 0, 4),
+            ("%all-reduce.2 = bf16[8]{0} all-reduce(%b)", 4, 1),
+            ("%fusion.3 = bf16[8]{0} fusion(%c)", 5, 5)]
+
+    def chip(ticks):
+        return {"XLA Modules": [("jit__probed_decode(1)", 20 * k, 10)
+                                for k in ticks],
+                "XLA Ops": [(n, 20 * k + s, d) for k in ticks
+                            for n, s, d in tick]}
+
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace([
+        ("/device:TPU:0", chip([0, 1])), ("/device:TPU:1", chip(range(4))),
+        ("/host:CPU", {"main": [("bench.window", 0, 80),
+                                ("other", 10, 5)]})]))
+    tr = tracereduce.load(str(path))
+    assert [d["id"] for d in tr["devices"]] == [0, 1]
+    assert {n for n, _, _ in tr["devices"][0]["ops"]} == {None}
+    assert [n for n, _, _ in tr["devices"][1]["ops"]][:3] == \
+        [n for n, _, _ in tick]
+    assert tr["host"] == [("bench.window", 0, 80)]
+    every = tracereduce.load(str(path), every_name=True)
+    assert {n for n, _, _ in every["devices"][0]["ops"]} == \
+        {n for n, _, _ in tick}
+    window = tracereduce.window(tr)
+    red = tracereduce.reduce(tr, *window)
+    assert red == tracereduce.reduce(every, *window)
+    assert red["devices_read"] == [1]
+    assert red["device_ops"][0][0] == "_probed_decode/%fusion.3 fusion bf16[8]"
+
+
+def test_one_tp4_decode_tick_of_a_recorded_trace():
+    """A decode tick of granite-8b-tp4 on chip 0 of a v5e 2x2 host: two
+    all-reduces in each of its 36 layers (the attention output and the
+    MLP's down projection, whose inputs are split over the chips and
+    whose weights are whole on each), one for the embedding lookup from
+    the vocabulary-split table, and the tied head's all-gather of that
+    table as an asynchronous collective fusion."""
+    import types
+
+    tr = _json("testdata", "tp4_tick_excerpt.json")
+    ops = tr["devices"][0]["ops"]
+    names = [n.split(" ")[0].rsplit(".", 1)[0] for n, _, _ in ops
+             if tracereduce.is_collective(n)]
+    assert names.count("%all-reduce") == 2 * 36 + 1
+    assert {n for n in names if n != "%all-reduce"} == {
+        "%async-collective-start", "%async-collective-done"}
+    red = tracereduce.reduce(tr, *tracereduce.window(tr))
+    tick = red["module_s"]["_probed_decode"]
+    c = red["collective_s"]["_probed_decode"]
+    # nothing else runs while the collectives run on this chip
+    assert c["exposed"] == pytest.approx(c["all"])
+    assert c["all"] == pytest.approx(
+        sum(d for n, _, d in ops if tracereduce.is_collective(n)) * 1e-9)
+    share = harness.reader("collective_share.sat")(
+        types.SimpleNamespace(trace=red))
+    assert share == pytest.approx(100 * c["exposed"] / tick)
+    assert 5.0 < share < 8.0
+
+
+def test_collective_share_reads_the_decode_programs_only():
+    import types
+
+    read = harness.reader("collective_share.sat")
+    trace = {"module_s": {"_probed_decode": 0.5, "_probed_scan": 1.5,
+                          "_chunk_step": 1.0},
+             "collective_s": {"_probed_scan": {"all": 0.3, "exposed": 0.1},
+                              "_chunk_step": {"all": 0.5, "exposed": 0.5}}}
+    assert read(types.SimpleNamespace(trace=trace)) == pytest.approx(5.0)
+    trace["collective_s"].pop("_probed_scan")
+    assert read(types.SimpleNamespace(trace=trace)) is None
+    assert read(types.SimpleNamespace(trace=None)) is None
+
+
 # --- finding cells and readers by name --------------------------------------
 
 
@@ -243,6 +433,14 @@ def test_every_listed_metric_has_a_reader_and_every_cell_its_files():
         assert cell.per_layer, w["name"]
         for m in cell.per_layer:
             assert callable(harness.reader(m["name"]))
+
+
+def test_every_cell_asks_for_the_chips_its_engine_spans():
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        bm = json.load(f)
+    for w in bm["workloads"]:
+        cell = harness.find_cell(w["name"])
+        assert harness.topology(cell.conf).n_chips == cell.chips, w["name"]
 
 
 # --- run.py without a chip ------------------------------------------------
@@ -334,6 +532,97 @@ def test_the_int8_control_fails_where_bf16_passes(family, mix, seed):
         cell = tiny_cell(family, mix, d=256, vocab=16384, layers=4)
         cell.conf["engine"]["precision"] = prec
         assert _run(cell, seed)["correct"] is want, prec
+
+
+@pytest.mark.parametrize("seed", [3, 11])
+def test_the_int8_reference_control_fails_the_check(seed):
+    """The control of a cell whose engine refuses int8 weights: the
+    reference with int8 weights in the program's place, at the positions
+    of served requests (``control.py``). Judged without a window, on
+    requests drawn from the seed: the sound served tokens of this tiny
+    cell read under 0.001 (``test_served_tokens_pass_the_check``)."""
+    import types
+
+    cell = tiny_cell("chatglm", "chat-sat", d=256, vocab=16384, layers=4)
+    sample = [types.SimpleNamespace(
+        spec=types.SimpleNamespace(rid=rid, sampled=bool(rid % 2)),
+        req=types.SimpleNamespace(
+            prompt=traffic.prompt_tokens(seed, rid, 300, 16384),
+            output=list(traffic.prompt_tokens(seed, 100 + rid, 60, 16384))))
+        for rid in range(4)]
+    gaps, n = harness.check(cell.conf, cell.mix, seed, sample, control=True)
+    assert n == 4 * 60
+    limit = cell.load["check"]["mean_logit_gap"]
+    assert gaps["mean_logit_gap"][0] > limit
+
+
+def test_a_sharded_engine_with_int8_weights_is_refused_before_any_draw():
+    cell = tiny_cell("llama", "chat-sat")
+    cell.conf["engine"].update(topology={"tp": 4}, precision={
+        "kv_cache_dtype": "int8", "weight_dtype": "int8"})
+    with pytest.raises(SystemExit, match="int8ref"):
+        harness.build(cell.conf, 1, False)
+
+
+@pytest.mark.parametrize("part_s", [None, 0.8])
+def test_a_traced_run_profiles_and_counts_the_same_span(monkeypatch, part_s):
+    """Without ``trace_seconds`` the profile spans the window and the
+    readers get the window's work; with it, the profiler starts when that
+    many seconds of the window are left, and the readers get the work,
+    host seconds and page shares of that part alone. The profile is
+    recorded (on the CPU) and its ``bench.window`` read back."""
+    import glob
+
+    import jax
+
+    cell = tiny_cell("llama", "chat-sat")
+    if part_s:
+        cell.load["trace_seconds"] = part_s
+    seconds, seen, starts = 2.0, {}, []
+    real_window, real_start = harness.run_window, jax.profiler.start_trace
+
+    def run_window(d, *a, **k):
+        seen["d"] = d
+        seen["out"] = real_window(d, *a, **k)
+        return seen["out"]
+
+    def start_trace(path):
+        starts.append(time.perf_counter())
+        real_start(path)
+
+    def per_layer(cell, span, window, prof_dir, peak, slots):
+        path, = glob.glob(os.path.join(prof_dir, "**", "*.xplane.pb"),
+                          recursive=True)
+        tr = tracereduce.load(path)
+        t0, t1 = tracereduce.window(tr)
+        seen.update(span=span, traced_s=(t1 - t0) * 1e-9, steps=sum(
+            1 for n, s, _ in tr["host"] if n == "bench.step" and t0 <= s < t1))
+        return {"metrics": {}, "busy_s": 1.0, "trace_window_s": 1.0,
+                "devices_read": [0], "breakdown": {}}
+
+    monkeypatch.setattr(harness, "run_window", run_window)
+    monkeypatch.setattr(harness, "per_layer", per_layer)
+    monkeypatch.setattr(jax.profiler, "start_trace", start_trace)
+    r = harness.run_cell(cell, 2**31 + 9, seconds, True,
+                         t_start=time.perf_counter(), peak=None,
+                         log=lambda m: None)
+    d, out, span = seen["d"], seen["out"], seen["span"]
+    assert r["correct"] and len(starts) == 1
+    assert seen["traced_s"] == pytest.approx(span["window_s"], abs=0.01)
+    assert abs(seen["steps"] - len(span["pages_share"])) <= 1
+    whole = d.work
+    if not part_s:
+        assert starts[0] < d.t0 and out["part"] is None
+        assert span["work"] is whole and span["window_s"] == out["window_s"]
+        assert span["pages_share"] == d.pages_share
+        return
+    assert starts[0] - d.t0 >= seconds - part_s
+    # less the profiler's start, plus the step that crosses the close
+    assert part_s / 2 < span["window_s"] < part_s + 0.3
+    assert 0 < span["work"]["ticks"] < whole["ticks"]
+    assert 0 < span["work"]["output_tokens"] < whole["output_tokens"]
+    assert 0 < span["work"]["decode_bytes"] < whole["decode_bytes"]
+    assert 0 < len(span["pages_share"]) < len(d.pages_share)
 
 
 def test_a_token_altered_where_it_is_produced_fails_the_check(monkeypatch):
