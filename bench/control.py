@@ -2,12 +2,13 @@
 weights rounded to int8, put in the served program's place.
 
 The configurations state bfloat16; int8 is the step below it that a
-later change could be tempted by. Every matrix is rounded to int8 with
-one scale per output column (the embedding, which is also the tied
-head, one per row), in the published layout and the served dtype, and
-the reference's forward pass runs on those weights. At each position of
-a served request's prompt and served tokens the control picks its token
-as the lane does: a greedy lane the int8 reference's best, a sampled
+later change could be tempted by. Every matrix that the model family
+lists in its ``matrices`` is rounded to int8 with one scale per output
+column (the dense families' embedding, which is also the tied head, one
+per row), in the published layout and the served dtype, and the
+family's reference forward pass runs on those weights. At each position
+of a served request's prompt and served tokens the control picks its
+token as the lane does: a greedy lane the int8 reference's best, a sampled
 lane a draw from the int8 reference's distribution under the mix's
 temperature, top-k and top-p. The float32 reference then judges those
 picks as ``reference.gaps`` judges the served tokens. A sharded
@@ -24,11 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import reference
-
-#: matrices of the published layout and the axis their scales run along
-#: (the input axis, so that each output column has its own scale)
-MATRICES = {"wq": -2, "wk": -2, "wv": -2, "wo": -2, "w_gate": -2,
-            "w_up": -2, "w_down": -2, "lm_head": -2, "embed": -1}
+from weights import family_of
 
 
 def _int8(m, axis: int):
@@ -38,17 +35,18 @@ def _int8(m, axis: int):
     return (q * scale).astype(m.dtype)
 
 
-@jax.jit
-def int8_weights(w: dict) -> dict:
-    """``w`` (``weights.published``) with every matrix rounded to int8."""
-    return {k: _int8(v, MATRICES[k]) if k in MATRICES else v
-            for k, v in w.items()}
+@functools.partial(jax.jit, static_argnums=0)
+def int8_weights(s, w: dict) -> dict:
+    """``w`` (``weights.published``) with every matrix of the family's
+    ``matrices`` rounded to int8, its scales along the axis given there."""
+    axes = family_of(s).matrices
+    return {k: _int8(v, axes[k]) if k in axes else v for k, v in w.items()}
 
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def _picks(s, w8, tokens, sampling, key):
     """The int8 reference's greedy and sampled token at every row."""
-    logits = reference._logits(s, w8, tokens)
+    logits = family_of(s).logits(s, w8, tokens)
     temperature, top_k, top_p = sampling
     top, idx = jax.lax.top_k(logits / temperature,
                              top_k if top_k > 0 else logits.shape[-1])

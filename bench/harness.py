@@ -2,10 +2,12 @@
 
 A cell is an entry of ``BENCHMARK.json``'s ``workloads``. Everything it
 names is data found by name: ``configs/<config>.json`` (model and engine),
-``traffic/<traffic>.json`` (the mix), ``cells/<cell>.json`` (the cell's
-own rate or clients, the limit of its correctness check and, where a
-traced run profiles only the window's last seconds, ``trace_seconds``), and
-``metrics/<metric>.py`` (one reader per per-layer metric).
+``families/<family>.py`` (the configuration's model family: its shapes,
+weights, reference and work counts), ``traffic/<traffic>.json`` (the
+mix), ``cells/<cell>.json`` (the cell's own rate or clients, the limit
+of its correctness check and, where a traced run profiles only the
+window's last seconds, ``trace_seconds``), and ``metrics/<metric>.py``
+(one reader per per-layer metric).
 
 The window drives the user's entry: ``ServingEngine.submit`` / ``step``
 from an ``EngineConfig``, with ``now`` the wall-clock seconds since the
@@ -16,10 +18,12 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import importlib.util
 import json
 import math
+import operator
 import os
 import sys
 import tempfile
@@ -37,7 +41,8 @@ sys.path[:0] = [os.path.join(ROOT, "src"), BENCH,
 
 import flops  # noqa: E402
 import traffic  # noqa: E402
-from weights import published, seed_key, shapes, to_program  # noqa: E402
+from weights import (  # noqa: E402
+    family_of, published, seed_key, shapes, to_program)
 
 #: request states after which the engine does no more work on it
 TERMINAL = ("finished", "cancelled", "timed_out", "failed", "shed")
@@ -123,18 +128,14 @@ class CompileCount:
 
 def program_config(conf: dict):
     """The program's ArchConfig for a configuration file, checked against
-    the file's published widths."""
+    the fields its model family asks of it (``program_check``)."""
     from repro.configs import get_config
 
     p = conf["program"]
     cfg = dataclasses.replace(get_config(p["arch"]), **p.get("overrides", {}))
     s = shapes(conf)
-    got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-           cfg.resolved_head_dim, cfg.d_ff, cfg.vocab_size, cfg.rope_theta,
-           cfg.rope_variant, cfg.tie_embeddings, cfg.arch_type)
-    want = (s.layers, s.d, s.heads, s.kv_heads, s.head_dim, s.ff, s.vocab,
-            s.rope_theta, "half" if s.family == "chatglm" else "standard",
-            s.tied, "dense")
+    want = family_of(s).program_check(s)
+    got = {k: getattr(cfg, k) for k in want}
     if got != want:
         raise SystemExit(f"program config {p} does not match the file's "
                          f"shapes: {got} != {want}")
@@ -176,15 +177,15 @@ def weights_program(cfg, s, topo, int8_weights: bool = False):
     if not topo.sharded:
         return jax.jit(init)
     out = placement(cfg, topo)
-    attn = out["body"][0]["attn"]
+    first = {name: functools.reduce(operator.getitem, path, out)
+             for name, path in family_of(s).split_first.items()}
 
     def init_placed(k):
-        # to_program permutes the columns of wq and wk (a gather): split
-        # them as the engine keeps them first, or GSPMD draws each whole
-        # on every chip
+        # the family's ``split_first`` leaves are split as the engine
+        # keeps them before ``to_program`` reads them
         w = published(s, k)
-        for name in ("wq", "wk"):
-            w[name] = jax.lax.with_sharding_constraint(w[name], attn[name])
+        for name, sharding in first.items():
+            w[name] = jax.lax.with_sharding_constraint(w[name], sharding)
         return to_program(s, w)
 
     return jax.jit(init_placed, out_shardings=out)
@@ -369,8 +370,9 @@ def warm_up(engine, s, mix: dict, conf: dict, seed: int) -> None:
 
 def _tally(d: Client, ticks: int) -> dict:
     """The window's work so far, with ``ticks`` decode ticks."""
+    lanes = d.work["decode_tokens"] / ticks if ticks else 0.0
     return dict(d.work, ticks=ticks, decode_bytes=flops.decode_bytes(
-        d.s, ticks, [d.contexts]))
+        d.s, ticks, [d.contexts], lanes))
 
 
 def run_window(d: Client, cell: Cell, seconds: float, on_close,
@@ -458,37 +460,17 @@ def run_window(d: Client, cell: Cell, seconds: float, on_close,
 # ---------------------------------------------------------------------------
 
 
-def published_shardings(s, topo) -> dict:
-    """Shardings of the published weights over the chips of a sharded
-    engine: every stacked matrix split on its last axis, the embedding
-    on the vocabulary, norms whole on each chip. The reference's einsums
-    are then partitioned by GSPMD, and no chip holds more than its share
-    of the weights, a layer of them in float32 and one sequence's
-    logits."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    from repro.launch.mesh import make_serving_mesh
-
-    mesh = make_serving_mesh(topo)
-    last = NamedSharding(mesh, P(None, None, "model"))
-    whole = NamedSharding(mesh, P())
-    out = {k: last for k in ("wq", "wk", "wv", "wo", "w_gate", "w_up",
-                             "w_down")}
-    out.update(embed=NamedSharding(mesh, P("model", None)),
-               attn_norm=whole, mlp_norm=whole, final_norm=whole)
-    if not s.tied:
-        out["lm_head"] = NamedSharding(mesh, P(None, "model"))
-    return out
-
-
 def published_program(s, topo):
     """The jitted call that draws the published weights from a key for the
     reference: whole on one chip, and over a sharded engine's chips split
-    by ``published_shardings``."""
+    by the model family's ``published_shardings`` on the engine's mesh."""
     if not topo.sharded:
         return jax.jit(lambda k: published(s, k))
+    from repro.launch.mesh import make_serving_mesh
+
+    mesh = make_serving_mesh(topo)
     return jax.jit(lambda k: published(s, k),
-                   out_shardings=published_shardings(s, topo))
+                   out_shardings=family_of(s).published_shardings(s, mesh))
 
 
 #: the number each lane's served tokens are judged by
@@ -534,7 +516,7 @@ def check(conf: dict, mix: dict, seed: int, sample: List[Rec],
     sp = mix["sampling"]
     sampling = (sp["temperature"], sp["top_k"], sp["top_p"])
     w = published_program(s, topology(conf))(seed_key(seed))
-    w8 = ctl.int8_weights(w) if control else None
+    w8 = ctl.int8_weights(s, w) if control else None
     lanes = {}
     for rec in sample:
         if control:

@@ -668,7 +668,7 @@ def test_a_sampler_fault_fails_the_sampled_lane(monkeypatch, fault):
 def test_a_wrong_rope_layout_fails_the_check(monkeypatch):
     import weights
 
-    monkeypatch.setattr(weights, "rope_permutation",
+    monkeypatch.setattr(weights.family("chatglm"), "rope_permutation",
                         lambda s: np.arange(s.head_dim))
     cell = copy.deepcopy(tiny_cell("chatglm"))
     assert not _run(cell, 12)["correct"]
