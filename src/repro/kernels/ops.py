@@ -2,8 +2,10 @@
 
 On a TPU backend the kernels run natively; on the CPU dry-run container
 ``interpret=True`` executes the kernel bodies in Python for correctness
-validation (the models' default compute path stays pure-jnp — see
-DESIGN.md §7).
+validation. The served path takes one of them: a paged decode step
+lowered for TPU calls ``paged_decode_attention`` (see
+``repro.models.layers.served_paged_decode_attention``); the rest of the
+models' compute is pure jnp.
 """
 from __future__ import annotations
 
@@ -65,27 +67,22 @@ def decode_attention(q, k_cache, v_cache, pos, *, interpret: bool = None):
 @partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention(q, k_pool, v_pool, page_table, pos, *,
                            interpret: bool = None):
-    """q: (B, S, H, D); k/v_pool: (P, ps, Hkv, D) shared page pools;
-    page_table: (B, n_pages) int32; pos: (B,) tokens written INCLUDING the
-    S queries. Model-layout twin of ``repro.models.layers.
-    paged_decode_attention`` running the block-sparse Pallas kernel."""
+    """q: (B, 1, H, D); k/v_pool: (P, ps, Hkv, D) page pools (or a layer
+    scan's stacked pools as rows), read in place; page_table: (B, n_pages)
+    int32 pool rows; pos: (B,) tokens written INCLUDING the query.
+    Model-layout twin of ``repro.models.layers.paged_decode_attention``
+    for bf16 pools, S=1, an even Hkv and D a multiple of 128."""
     if interpret is None:
         interpret = _default_interpret()
     b, sq, h, d = q.shape
-    ps, hkv = k_pool.shape[1], k_pool.shape[2]
-    g = h // hkv
-    n_pages = page_table.shape[1]
-    # (B, S, H, D) -> (B, KVH, G*S, D), rows (g, s)-ordered
-    qf = (q.reshape(b, sq, hkv, g, d).transpose(0, 2, 3, 1, 4)
-          .reshape(b, hkv, g * sq, d))
-    kf = k_pool.transpose(2, 0, 1, 3)  # (KVH, P, ps, D)
-    vf = v_pool.transpose(2, 0, 1, 3)
-    nv = jnp.minimum(pos, n_pages * ps).astype(jnp.int32)
+    hkv = k_pool.shape[2]
+    assert sq == 1, sq
+    # (B, 1, H, D) -> (B, Hkv, G, D): head c * G + g is kv head c's g-th
     o = _da.paged_decode_attention(
-        qf, kf, vf, page_table.reshape(-1).astype(jnp.int32), nv, s_q=sq,
+        q.reshape(b, hkv, h // hkv, d), k_pool, v_pool,
+        page_table.astype(jnp.int32), pos.astype(jnp.int32),
         interpret=interpret)
-    return (o.reshape(b, hkv, g, sq, d).transpose(0, 3, 1, 2, 4)
-            .reshape(b, sq, h, d))
+    return o.reshape(b, 1, h, d)
 
 
 @partial(jax.jit, static_argnames=("interpret",))

@@ -226,8 +226,8 @@ def _paged_attn_decode(cfg, q, k, v, cache, pages, pos, layer=None):
                 q, rows["k"], rows["v"], rows["k_scale"], rows["v_scale"],
                 pages, pos_b + s)
         else:
-            out = L.paged_decode_attention(q, rows["k"], rows["v"], pages,
-                                           pos_b + s)
+            out = L.served_paged_decode_attention(
+                q, rows["k"], rows["v"], pages, pos_b + s)
     return out, {name: a.reshape(cache[name].shape)
                  for name, a in rows.items()}
 

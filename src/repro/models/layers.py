@@ -374,8 +374,9 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos):
     reuses the rolling-cache masked softmax (``decode_attention``), so the
     numerics are identical to a rolling window of width n_pages*ps —
     garbage in not-yet-written page slots is hidden by the same per-query
-    validity mask. This is the jnp oracle twin of the block-sparse Pallas
-    kernel in ``repro.kernels.decode_attention.paged_decode_attention``.
+    validity mask. This is the jnp oracle of the Pallas kernel in
+    ``repro.kernels.decode_attention.paged_decode_attention``, and the
+    path wherever ``served_paged_decode_attention`` does not take it.
     """
     b = q.shape[0]
     _, ps, hkv, d = k_pool.shape
@@ -383,6 +384,35 @@ def paged_decode_attention(q, k_pool, v_pool, page_table, pos):
     k = jnp.take(k_pool, page_table, axis=0).reshape(b, n_pages * ps, hkv, d)
     v = jnp.take(v_pool, page_table, axis=0).reshape(b, n_pages * ps, hkv, d)
     return decode_attention(q, k, v, pos)
+
+
+def paged_kernel_applies(q, k_pool) -> bool:
+    """Whether the Pallas paged kernel can serve this call, judged from
+    what the trace shows: one query per slot, bf16 query and pools, an
+    even number of kv heads of a multiple of 128, and pools on one device
+    (a sharded engine's pools carry their mesh into every trace, and a
+    kernel over pools split by kv head would gather them)."""
+    hkv, d = k_pool.shape[-2:]
+    mesh = jax.typeof(k_pool).sharding.mesh
+    return (q.shape[1] == 1 and q.dtype == k_pool.dtype == jnp.bfloat16
+            and hkv % 2 == 0 and d % 128 == 0
+            and (mesh.empty or mesh.size == 1))
+
+
+def served_paged_decode_attention(q, k_pool, v_pool, page_table, pos):
+    """``paged_decode_attention`` as the engine serves it: where
+    ``paged_kernel_applies``, a program lowered for TPU reads the pools
+    in place through the Pallas kernel (only each slot's ``pos`` tokens,
+    no gather, no mask over the whole row); every other platform and call
+    takes the oracle. The arguments are those of the oracle."""
+    if not paged_kernel_applies(q, k_pool):
+        return paged_decode_attention(q, k_pool, v_pool, page_table, pos)
+    from repro.kernels import ops
+
+    return jax.lax.platform_dependent(
+        q, k_pool, v_pool, page_table, pos,
+        tpu=partial(ops.paged_decode_attention, interpret=False),
+        default=paged_decode_attention)
 
 
 def paged_decode_attention_int8(q, k_pool, v_pool, k_scale, v_scale,

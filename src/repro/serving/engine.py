@@ -1712,12 +1712,20 @@ class ServingEngine:
     def _advance_pos(self, n: int):
         """Advance the host mirror of each decoding slot's cache position
         (paged mode tracks it to pre-allocate decode pages without a
-        device sync)."""
+        device sync) over ``n`` decode ticks, and count the pages their
+        attention reads: tick k of a slot at position p attends p + k + 1
+        tokens."""
         if not self.paged:
             return
+        ps, m = self.page_size, self.metrics
+        m.decode_kv_pages_row += n * self.slots * self.max_pages
         for i, d in enumerate(self.decoding):
             if d:
-                self._pos_h[i] += n
+                p = self._pos_h[i]
+                m.decode_kv_pages_read += sum(
+                    -(-min(p + k, self.max_seq) // ps)
+                    for k in range(1, n + 1))
+                self._pos_h[i] = p + n
 
     def _ensure_headroom(self, n: int, now: float = 0.0):
         """Write every decoding slot enough page-table entries to absorb

@@ -354,6 +354,11 @@ class ServeMetrics:
     sla_violations: int = 0
     decode_ticks: int = 0  # batched decode steps executed
     fused_ticks: int = 0  # ...of them run inside the fused scan window
+    # paged decode attention: pages it reads, ceil(context / page size)
+    # summed over decode ticks and decoding lanes, and the whole-row read
+    # it replaced, ticks x slots x a slot's table width
+    decode_kv_pages_read: int = 0
+    decode_kv_pages_row: int = 0
     host_syncs: int = 0  # device->host token transfers (1 per N ticks)
     prefill_chunks: int = 0  # chunked-prefill pieces interleaved with decode
     # --- shared-prefix KV cache ---
@@ -449,6 +454,8 @@ class ServeMetrics:
         self.sla_violations += other.sla_violations
         self.decode_ticks += other.decode_ticks
         self.fused_ticks += other.fused_ticks
+        self.decode_kv_pages_read += other.decode_kv_pages_read
+        self.decode_kv_pages_row += other.decode_kv_pages_row
         self.host_syncs += other.host_syncs
         self.prefill_chunks += other.prefill_chunks
         self.prefix_hits += other.prefix_hits
@@ -500,7 +507,8 @@ class ServeMetrics:
         for f in ("completed", "total_tokens", "rejected", "cancelled",
                   "timed_out", "shed", "browned_out", "failed", "preempted",
                   "preempt_restores", "retried", "failed_over",
-                  "decode_ticks", "fused_ticks", "host_syncs",
+                  "decode_ticks", "fused_ticks", "decode_kv_pages_read",
+                  "decode_kv_pages_row", "host_syncs",
                   "prefill_chunks", "prefix_hits", "prefix_hit_tokens",
                   "sampled_requests",
                   "slo_tracked", "slo_met", "ttft_slo_misses",
