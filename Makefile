@@ -1,12 +1,8 @@
-# One-word entry points for the repo's verify + bench loops.
+# One-word entry points for the repo's verify loop. The benchmark is
+# bench/run.py (BENCHMARK.json); it runs on the chip, not from here.
 PY := PYTHONPATH=src$(if $(PYTHONPATH),:$(PYTHONPATH)) python
 
-.PHONY: test lint bench bench-smoke bench-cluster bench-cluster-smoke \
-	bench-prefix bench-prefix-smoke bench-sampling bench-sampling-smoke \
-	bench-chaos bench-chaos-smoke bench-sharded bench-sharded-smoke \
-	bench-observability bench-observability-smoke trace-demo \
-	bench-overload bench-overload-smoke bench-quant bench-quant-smoke \
-	span-diff span-baseline serve-bench micro
+.PHONY: test lint
 
 # tier-1 verify (ROADMAP.md)
 test:
@@ -15,115 +11,3 @@ test:
 # pyflakes-critical lint tier (ruff.toml); check-only — CI never autofixes
 lint:
 	ruff check --no-fix .
-
-# serving perf trajectory: engine vs pre-refactor baseline -> BENCH_serving.json
-bench:
-	$(PY) benchmarks/serving_bench.py
-
-# CI gate: tiny serving run failing on compile-count regressions
-bench-smoke:
-	$(PY) benchmarks/serving_bench.py --smoke
-
-# cluster routing-policy A/B (virtual time) -> BENCH_cluster.json
-bench-cluster:
-	$(PY) benchmarks/cluster_bench.py
-
-# CI gate: tiny 2-replica cluster run failing on routing-invariant,
-# stream-identity, page-leak, or compile-count regressions
-bench-cluster-smoke:
-	$(PY) benchmarks/cluster_bench.py --smoke
-
-# shared-prefix KV cache A/B (warm vs cold TTFT) -> BENCH_prefix.json
-bench-prefix:
-	$(PY) benchmarks/prefix_bench.py
-
-# CI gate: tiny prefix-cache A/B failing on the >=5x warm-TTFT headline,
-# stream identity, page/refcount leaks, or suffix-trace growth
-bench-prefix-smoke:
-	$(PY) benchmarks/prefix_bench.py --smoke --out BENCH_prefix_smoke.json
-
-# stochastic vs greedy decode A/B (equal batch) -> BENCH_sampling.json
-bench-sampling:
-	$(PY) benchmarks/sampling_bench.py
-
-# CI gate: seeded sampled workload replayed across slot orders + an
-# engine restart; fails on stream divergence or decode-trace growth
-bench-sampling-smoke:
-	$(PY) benchmarks/sampling_bench.py --smoke
-
-# chaos harness: kill/hang/slow one of four replicas mid-workload plus a
-# preemption-churn round -> BENCH_chaos.json
-bench-chaos:
-	$(PY) benchmarks/chaos_bench.py
-
-# CI gate: tiny chaos run failing on lost requests, non-bit-identical
-# failed-over streams, survivor page/refcount leaks, unbounded retries,
-# goodput retention < 0.70, or a watchdog mis-verdict (slow declared dead)
-bench-chaos-smoke:
-	$(PY) benchmarks/chaos_bench.py --smoke
-
-# tensor/expert-parallel replica vs 1-chip on the same workload (the
-# script forces 8 XLA host devices itself) -> BENCH_sharded.json
-bench-sharded:
-	$(PY) benchmarks/sharded_bench.py
-
-# CI gate: fails on sharded-vs-1-chip stream divergence, compile-count
-# growth under the mesh, page leaks, or MoE expert-parallel divergence
-bench-sharded-smoke:
-	$(PY) benchmarks/sharded_bench.py --smoke
-
-# observability layer A/B: histogram-percentile parity, trace lifecycle
-# accounting, bit-identity, tracing overhead -> BENCH_observability.json
-bench-observability:
-	$(PY) benchmarks/observability_bench.py
-
-# CI gate: fails on percentile drift past one bucket, malformed or
-# incomplete span traces, stream divergence with tracing on, or tracing
-# overhead past the noise-tolerant 0.90 bound (acceptance: 0.97 full)
-bench-observability-smoke:
-	$(PY) benchmarks/observability_bench.py --smoke
-
-# multi-tenant overload stack under a low-tier flood: SLO-tier goodput
-# retention, DRR fairness bounds, ladder engagement, bit-identity of
-# admitted streams, typed retry-after -> BENCH_overload.json
-bench-overload:
-	$(PY) benchmarks/overload_bench.py
-
-# CI gate: fails on protected-tier goodput retention < 0.9 under the
-# flood, a starved tenant (DRR wait past its provable bound), a ladder
-# that never engaged, stream divergence vs the unloaded reference, or a
-# rejection missing its finite retry_after_s
-bench-overload-smoke:
-	$(PY) benchmarks/overload_bench.py --smoke \
-		--out BENCH_overload.json
-
-# quantized-serving A/B: int8 KV pages vs the f32 pool on one workload
-# (capacity, decode tok/s, stream divergence, kernel error-vs-bound)
-# -> BENCH_quant.json
-bench-quant:
-	$(PY) benchmarks/quant_bench.py
-
-# CI gate: fails on slots ratio < 1.8x at equal HBM, decode tok/s
-# < 0.9x f32, a diverged FIRST token (prefill must stay exact), an
-# unbounded stream rewrite, or kernel error past the closed-form bound
-bench-quant-smoke:
-	$(PY) benchmarks/quant_bench.py --smoke --out BENCH_quant_smoke.json
-
-# span-phase triage gate: per-kind span rollups of a fixed virtual-time
-# traced workload diffed against benchmarks/SPAN_BASELINE.json — fails
-# NAMING the regressed phase; deliberate changes: make span-baseline
-span-diff:
-	$(PY) benchmarks/span_diff.py
-
-span-baseline:
-	$(PY) benchmarks/span_diff.py --update
-
-# viewable trace artifact: a small chaos run (kill/hang/slow + churn)
-# exported as TRACE_chaos.json — open it in https://ui.perfetto.dev
-trace-demo:
-	$(PY) benchmarks/chaos_bench.py --requests 24 \
-		--trace-out TRACE_chaos.json --out ""
-
-# wall-clock microbenchmarks of the jitted steps
-micro:
-	$(PY) -m benchmarks.run --only micro

@@ -1,75 +1,35 @@
-"""MISD example: multi-tenant serving with spatial meshlets + temporal
-scheduling (survey §3) — partition a 256-chip pod for three tenant models,
-then co-schedule a mixed query stream with each scheduler and compare.
+"""MISD example: size each tenant's batch to its latency SLA (survey §3) —
+three tenant models, each on an 8-chip slice, batched as large as the
+cost model says its decode step can go while staying inside the SLA.
 
     PYTHONPATH=src python examples/multi_tenant_serving.py
 """
-import copy
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import numpy as np
-
 from repro.configs import get_config
-from repro.core.costmodel import estimate_decode, stream_occupancy
-from repro.core.misd import (
-    SCHEDULERS,
-    Device,
-    Job,
-    MeshPartitioner,
-    MISDSimulator,
-    adaptive_batch_size,
-)
+from repro.core.misd import adaptive_batch_size
+
+CHIPS = 8
 
 
 def main():
     tenants = [
-        {"name": "chat", "cfg": get_config("chatglm3-6b"), "batch": 16,
-         "context": 4096, "sla_s": 0.05},
-        {"name": "code", "cfg": get_config("granite-8b"), "batch": 8,
-         "context": 8192, "sla_s": 0.08},
-        {"name": "vision", "cfg": get_config("qwen2-vl-7b"), "batch": 8,
-         "context": 4096, "sla_s": 0.10},
+        {"name": "chat", "cfg": get_config("chatglm3-6b"), "context": 4096,
+         "sla_s": 0.05},
+        {"name": "code", "cfg": get_config("granite-8b"), "context": 8192,
+         "sla_s": 0.08},
+        {"name": "vision", "cfg": get_config("qwen2-vl-7b"), "context": 4096,
+         "sla_s": 0.10},
     ]
-
-    # --- spatial: gpulet-style meshlet partitioning ------------------------
-    part = MeshPartitioner((16, 16))
-    plan = part.plan(tenants)
-    print("meshlet plan:")
-    for m in plan.meshlets:
-        users = [k for k, v in plan.assignment.items() if v == m.name]
-        print(f"  {m.name}: {m.shape[0]}x{m.shape[1]} = {m.n_chips} chips "
-              f"-> {users}")
-
-    # --- adaptive batching per tenant --------------------------------------
+    print(f"adaptive batching, {CHIPS} chips per tenant:")
     for t in tenants:
-        mesh_name = plan.assignment[t["name"]]
-        chips = next(m.n_chips for m in plan.meshlets if m.name == mesh_name)
         b, lat = adaptive_batch_size(t["cfg"], context=t["context"],
-                                     sla_s=t["sla_s"], n_chips=chips)
-        print(f"  {t['name']}: adaptive batch={b} "
+                                     sla_s=t["sla_s"], n_chips=CHIPS)
+        print(f"  {t['name']}: batch={b} "
               f"(step {lat*1e3:.1f}ms <= SLA {t['sla_s']*1e3:.0f}ms)")
-
-    # --- temporal: scheduler comparison on one shared meshlet --------------
-    rng = np.random.default_rng(0)
-    jobs = []
-    t_arr = 0.0
-    for i in range(200):
-        ten = tenants[int(rng.integers(3))]
-        est = estimate_decode(ten["cfg"], 8, ten["context"], n_chips=64)
-        t_arr += float(rng.exponential(est.latency_s / 2.5))
-        jobs.append(Job(i, ten["name"], est.demand_at(stream_occupancy(8)),
-                        est.latency_s, arrival=t_arr,
-                        priority=5 if ten["name"] == "chat" else 0,
-                        sla_s=est.latency_s * 5))
-    print("\nscheduler comparison (one 64-chip meshlet, 4 tenants max):")
-    for name, cls in SCHEDULERS.items():
-        res = MISDSimulator([Device("meshlet", max_tenants=4)],
-                            cls()).run(copy.deepcopy(jobs))
-        print(f"  {name:20s} qps={res.qps:7.1f} jct={res.mean_jct()*1e3:7.1f}ms"
-              f" p99={res.p99_latency()*1e3:7.1f}ms sla={res.sla_attainment():.2f}")
 
 
 if __name__ == "__main__":

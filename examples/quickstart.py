@@ -1,4 +1,5 @@
-"""Quickstart: every quadrant of the survey's taxonomy in 60 seconds.
+"""Quickstart: serve a reduced model on the CPU, then a cost-model roofline
+of the full one.
 
     PYTHONPATH=src python examples/quickstart.py
 """
@@ -11,7 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
-from repro.core import Deployment, Paradigm, estimate, executor_for
+from repro.core import estimate
 from repro.configs import get_shape
 from repro.models import init_params
 from repro.serving import EngineConfig, Request, ServingEngine
@@ -23,7 +24,7 @@ def main():
     print(f"model: {cfg.name} ({cfg.arch_type}), "
           f"{cfg.param_count()/1e6:.1f}M params (reduced)")
 
-    # --- SISD: single-instance serving with continuous batching -----------
+    # --- single-instance serving with continuous batching ------------------
     params = init_params(cfg, jax.random.key(0))
     eng = ServingEngine(cfg, params, EngineConfig(slots=2, window=64))
     reqs = [Request(i, np.arange(8 + i, dtype=np.int32), max_new_tokens=6)
@@ -34,18 +35,11 @@ def main():
             queue.pop(0)
         eng.step(t)
         t += 1.0
-    print(f"SISD: served {eng.metrics.completed} requests, "
+    print(f"served {eng.metrics.completed} requests, "
           f"tokens={eng.metrics.total_tokens}")
 
-    # --- the taxonomy at production scale (full config, cost model) -------
+    # --- roofline for one assigned shape (full config, cost model) --------
     full = get_config("granite-8b")
-    for dep in (Deployment(full.name, 1, 1), Deployment(full.name, 4, 1),
-                Deployment(full.name, 1, 256), Deployment(full.name, 8, 256)):
-        p = dep.paradigm
-        print(f"{p.name}: I={dep.n_instances} D={dep.n_devices} -> "
-              f"{executor_for(p)}")
-
-    # --- roofline for one assigned shape ----------------------------------
     est = estimate(full, get_shape("decode_32k"), n_chips=256)
     print(f"decode_32k on 256 chips: compute={est.compute_s*1e3:.2f}ms "
           f"memory={est.memory_s*1e3:.2f}ms -> bottleneck={est.bottleneck}")

@@ -293,7 +293,6 @@ _ALIAS = {
     "llama4-maverick-400b-a17b": "llama4_maverick_400b",
     "hubert-xlarge": "hubert_xlarge",
     "qwen2-vl-7b": "qwen2_vl_7b",
-    "dlrm": "dlrm",
 }
 
 

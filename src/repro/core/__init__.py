@@ -1,6 +1,6 @@
-"""The survey's contribution — the SISD/MISD/SIMD/MIMD taxonomy — as a
-composable system: cost model + hardware constants at the root, one
-subpackage per quadrant (misd/, simd/, mimd/) and the SISD baseline."""
+"""The survey's SISD/MISD/SIMD/MIMD taxonomy as the serving stack uses it:
+cost model + hardware constants at the root, one subpackage per quadrant
+(misd/, simd/, mimd/)."""
 from repro.core.costmodel import (
     WorkEstimate,
     estimate,
@@ -10,4 +10,3 @@ from repro.core.costmodel import (
     model_flops,
 )
 from repro.core.hardware import CHIPS, TPU_V5E
-from repro.core.paradigm import Deployment, Paradigm, classify, executor_for

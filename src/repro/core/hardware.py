@@ -37,6 +37,3 @@ CHIPS = {c.name: c for c in (TPU_V5E, XEON_4116, RTX_2080TI, V100, A100)}
 
 # Fixed per-dispatch overhead (host->device launch, runtime) seconds.
 DISPATCH_OVERHEAD_S = 45e-6
-# Meshlet/partition reconfiguration cost (survey §3.3.2: "several seconds"
-# for MIG-class repartitioning; TPU analogue = recompile + weight reshard).
-RECONFIG_COST_S = 5.0

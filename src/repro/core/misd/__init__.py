@@ -9,16 +9,4 @@ from repro.core.misd.interference import (
     pairwise_degradation,
     progress_rates,
 )
-from repro.core.misd.partition import MeshPartitioner, Meshlet, PartitionPlan
-from repro.core.misd.scheduler import (
-    SCHEDULERS,
-    ChunkedPrefillPolicy,
-    Device,
-    FIFOScheduler,
-    InterferenceAwareScheduler,
-    Job,
-    MISDSimulator,
-    PremaScheduler,
-    SJFScheduler,
-    SimResult,
-)
+from repro.core.misd.scheduler import ChunkedPrefillPolicy, Device, Job

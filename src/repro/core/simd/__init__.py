@@ -1,11 +1,3 @@
-from repro.core.simd.embedding import (
-    batch_specs,
-    dlrm_forward,
-    init_dlrm,
-    lookup_traffic_bytes,
-    shard_specs,
-)
-from repro.core.simd.offload import OffloadPlan, effective_bandwidth, plan_offload, zipf_hit_rate
 from repro.core.simd.sharding import (
     ShardingPolicy,
     batch_pspecs,

@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.core.costmodel import estimate_backlog_s
+from repro.core.mimd.router import POLICIES
 from repro.core.misd.interference import InterferencePredictor
 from repro.models import init_params
 from repro.serving import (
@@ -550,3 +551,70 @@ def test_cluster_sampled_streams_stable_under_routing(pair):
     assert len(placements) > 1  # the policies really did place differently
     m = fe.merged_metrics()
     assert m.sampled_requests == 3  # cluster rollup counts sampled traffic
+
+
+@pytest.fixture(scope="module")
+def poisson_pair(granite):
+    """Two replicas at a width where long prompts take chunked prefill."""
+    cfg, params = granite
+    engines = [ServingEngine(cfg, params, EngineConfig(
+        slots=2, window=128, max_seq=192, sync_every=4)) for _ in range(2)]
+    return cfg, params, engines
+
+
+def _poisson_workload(n=24, *, rate=0.6, seed=0):
+    """Poisson arrivals of a bimodal mix: mostly short prompts with short
+    budgets, the rest long chunk-prefilled prompts with long budgets."""
+    rng = np.random.default_rng(seed)
+    arrivals = np.cumsum(rng.exponential(1.0 / rate, n))
+    reqs = []
+    for i in range(n):
+        short = rng.random() < 0.7
+        plen = int(rng.integers(8, 25) if short else rng.integers(48, 97))
+        budget = int(rng.integers(4, 9) if short else rng.integers(24, 41))
+        reqs.append(Request(i, rng.integers(0, 500, plen).astype(np.int32),
+                            budget, arrival_time=float(arrivals[i]),
+                            ttft_slo_s=6.0 if short else 16.0))
+    return reqs
+
+
+def _drive_arrivals(server, reqs, *, max_steps=5000):
+    """Submit each request when the clock passes its arrival; step once a
+    second until every request has finished."""
+    pending = sorted(reqs, key=lambda r: r.arrival_time)
+    i, now, done = 0, 0.0, 0
+    while done < len(reqs):
+        while i < len(pending) and pending[i].arrival_time <= now:
+            server.submit(pending[i], now)
+            i += 1
+        done += len(server.step(now))
+        now += 1.0
+        assert now < max_steps
+    server.drain(now)
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_cluster_policy_keeps_engine_invariants_under_poisson_arrivals(
+        poisson_pair, policy):
+    """Every routing policy, under Poisson arrivals of short and long
+    (chunked) prompts: streams equal one engine serving the same requests,
+    every replica drains its pages, each keeps the single-engine trace
+    budget (two decode traces; one prefill trace per prompt bucket and one
+    chunk trace), and round-robin reaches every replica."""
+    _, _, engines = poisson_pair
+    _reset(engines[0])
+    ref = _poisson_workload()
+    _drive_arrivals(engines[0], ref)
+    for eng in engines:
+        _reset(eng)
+    fe = ClusterFrontend(engines, policy=policy, seed=0)
+    reqs = _poisson_workload()
+    _drive_arrivals(fe, reqs)
+    assert {r.rid: r.output for r in reqs} == {r.rid: r.output for r in ref}
+    assert fe.merged_metrics().completed == len(reqs)
+    for eng in engines:
+        assert eng.allocator.pages_in_use == 0
+        assert eng.decode_traces <= 2
+        assert eng.prefill_traces <= 4
+    if policy == "round-robin":
+        assert all(inst.routed > 0 for inst in fe.instances)

@@ -266,3 +266,86 @@ def test_retry_backoff_delays_resubmission(granite):
     assert all(r.state is RequestState.FINISHED for r in resolved.values())
     # replay could not have finished before the backoff released (t>=5)
     assert all(r.finish_time >= 5.0 for r in held)
+
+
+# ---------------------------------------------------------------------------
+# one replica of four wedged or slowed mid-workload
+# ---------------------------------------------------------------------------
+
+
+def _poisson_sampled(n=24, *, rate=0.8, seed=0):
+    """Poisson arrivals; every request sampled (seed 7000+rid)."""
+    rng = np.random.default_rng(seed)
+    arrivals = np.cumsum(rng.exponential(1.0 / rate, n))
+    return [Request(i, rng.integers(0, 500, int(rng.integers(8, 33)))
+                    .astype(np.int32), int(rng.integers(8, 17)),
+                    arrival_time=float(arrivals[i]), ttft_slo_s=24.0,
+                    sampling=_samp(7000 + i))
+            for i in range(n)]
+
+
+def _fault_round(proxies, fault):
+    """The workload through a predicted-policy frontend over ``proxies``,
+    with ``fault`` injected into the first replica at the median arrival
+    (none for None). Returns the frontend and every resolved request."""
+    for p in proxies:
+        p.inject("recover")
+        p.engine.reset()
+    fe = ClusterFrontend(proxies, policy="predicted", seed=0,
+                         health_timeout_s=4.0, max_retries=3)
+    reqs = sorted(_poisson_sampled(), key=lambda r: r.arrival_time)
+    injector = FaultInjector({fe.instances[0].name: proxies[0]})
+    if fault is not None:
+        injector.schedule(reqs[len(reqs) // 2].arrival_time,
+                          fe.instances[0].name, fault, slow_every=4)
+    resolved, i, now = {}, 0, 0.0
+    while len(resolved) < len(reqs):
+        injector.tick(now)
+        while i < len(reqs) and reqs[i].arrival_time <= now:
+            fe.submit(reqs[i], now)
+            i += 1
+        for r in fe.step(now):
+            resolved[r.rid] = r
+        now += 1.0
+        assert now < 2000, f"{len(resolved)}/{len(reqs)} resolved"
+    for r in fe.drain(now):
+        resolved[r.rid] = r
+    return fe, resolved
+
+
+@pytest.fixture(scope="module")
+def four(granite):
+    """Four proxied replicas and the streams of a failure-free round."""
+    cfg, params = granite
+    proxies = [FaultyEngine(ServingEngine(cfg, params, EngineConfig(
+        slots=2, window=128, max_seq=192, sync_every=4)))
+        for _ in range(4)]
+    _, resolved = _fault_round(proxies, None)
+    return proxies, {rid: list(r.output) for rid, r in resolved.items()}
+
+
+@pytest.mark.parametrize("fault", ["hang", "slow"])
+def test_wedged_or_slow_replica_of_four_loses_nothing(four, fault):
+    """One replica of four hangs (only the watchdog can tell) or drops to a
+    quarter of its speed mid-workload: every request still finishes its
+    full budget with the failure-free stream, no request exceeds its retry
+    budget, the surviving replicas hold no page or reference, and only
+    the hung replica is declared dead."""
+    proxies, baseline = four
+    fe, resolved = _fault_round(proxies, fault)
+    assert len(resolved) == len(baseline)
+    assert all(r.state is RequestState.FINISHED
+               and len(r.output) == r.max_new_tokens
+               for r in resolved.values())
+    assert {rid: list(r.output) for rid, r in resolved.items()} == baseline
+    m = fe.merged_metrics()
+    assert max(r.retries for r in resolved.values()) <= 3
+    assert m.retried <= len(baseline)
+    for inst in fe.instances + fe.draining + fe.retired:
+        inst.engine.clear_prefix_cache()
+        assert inst.engine.allocator.pages_in_use == 0
+        assert inst.engine.allocator.total_refs == 0
+    if fault == "hang":
+        assert len(fe.failed) == 1 and m.failed_over > 0
+    else:
+        assert fe.failed == [] and m.failed_over == 0

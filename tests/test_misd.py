@@ -1,33 +1,15 @@
-"""MISD quadrant: interference model, schedulers, meshlets, batching.
-Validates the survey's §3 qualitative claims on our own stack."""
-import copy
-
-import numpy as np
-import pytest
-
+"""MISD quadrant: interference model and batching. Validates the survey's
+§3 qualitative claims on our own stack."""
 from repro.configs import get_config
 from repro.core.costmodel import estimate_decode
 from repro.core.misd import (
-    Device,
-    FIFOScheduler,
-    InterferenceAwareScheduler,
-    Job,
-    MeshPartitioner,
-    MISDSimulator,
-    PremaScheduler,
-    SJFScheduler,
     adaptive_batch_size,
     pairwise_degradation,
     progress_rates,
 )
-from repro.core.sisd import run_multi_tenant, run_single_tenant
 
 COMPUTE = (0.92, 0.25)  # compute-bound demand vector
 MEMORY = (0.18, 0.90)  # memory-bound demand vector
-
-
-def _jobs(n, demand, service=0.01, gap=0.004, **kw):
-    return [Job(i, "m", demand, service, arrival=i * gap, **kw) for i in range(n)]
 
 
 def test_rates_bounds_and_monotonicity():
@@ -43,97 +25,6 @@ def test_complementary_pairs_interfere_less():
     same = pairwise_degradation(COMPUTE, COMPUTE)
     assert mixed < same
     assert mixed < 1.35  # within the Fig. 3b 90th-percentile band
-
-
-def test_colocation_raises_throughput_with_bounded_latency():
-    """Fig. 3a: throughput up >= 25%, per-job latency degradation bounded."""
-    jobs = [Job(i, "m", COMPUTE if i % 2 else MEMORY, 0.01,
-                arrival=i * 0.002) for i in range(200)]
-    single = run_single_tenant(copy.deepcopy(jobs))
-    multi = run_multi_tenant(copy.deepcopy(jobs), max_tenants=2)
-    assert multi.qps > 1.25 * single.qps
-    assert multi.mean_slowdown() < 1.35
-
-
-def test_all_jobs_complete_and_conserve():
-    jobs = _jobs(50, COMPUTE)
-    res = MISDSimulator([Device("d0", 4)], FIFOScheduler()).run(
-        copy.deepcopy(jobs))
-    assert len(res.completed) == 50
-    for j in res.completed:
-        assert j.finish >= j.start >= 0
-        assert j.finish - j.start >= j.service_s - 1e-9  # no free lunch
-
-
-def test_sjf_beats_fifo_on_mean_jct():
-    rng = np.random.default_rng(0)
-    jobs = [Job(i, "m", COMPUTE, float(rng.uniform(0.002, 0.05)),
-                arrival=0.0) for i in range(40)]
-    fifo = MISDSimulator([Device("d0", 1)], FIFOScheduler()).run(
-        copy.deepcopy(jobs))
-    sjf = MISDSimulator([Device("d0", 1)], SJFScheduler()).run(
-        copy.deepcopy(jobs))
-    assert sjf.mean_jct() < fifo.mean_jct()
-
-
-def test_prema_prioritizes_high_priority_jobs():
-    """PREMA [5]: high-priority JCT improves vs FIFO under load."""
-    def mk():
-        jobs = _jobs(60, COMPUTE, service=0.02, gap=0.001)
-        for j in jobs[::6]:
-            j.priority = 8
-        return jobs
-
-    fifo = MISDSimulator([Device("d0", 2)], FIFOScheduler()).run(mk())
-    prema = MISDSimulator([Device("d0", 2)], PremaScheduler()).run(mk())
-
-    def hi_jct(res):
-        hi = [j for j in res.completed if j.priority > 0]
-        return np.mean([j.finish - j.arrival for j in hi])
-
-    assert len(prema.completed) == 60
-    assert hi_jct(prema) < hi_jct(fifo)
-    assert any(j.preemptions > 0 for j in prema.completed)
-
-
-def test_interference_aware_reduces_slowdown():
-    jobs = _jobs(80, COMPUTE, service=0.01, gap=0.0005)
-    fifo = MISDSimulator([Device("d0", 4), Device("d1", 4)],
-                         FIFOScheduler()).run(copy.deepcopy(jobs))
-    ia = MISDSimulator([Device("d0", 4), Device("d1", 4)],
-                       InterferenceAwareScheduler()).run(copy.deepcopy(jobs))
-    assert len(ia.completed) == 80
-    assert ia.mean_slowdown() <= fifo.mean_slowdown() + 1e-9
-
-
-# --- meshlets ---------------------------------------------------------------
-
-
-def test_partitioner_plans_within_pod():
-    part = MeshPartitioner((16, 16))
-    cfg_small = get_config("chatglm3-6b")
-    cfg_large = get_config("phi3-medium-14b")
-    plan = part.plan([
-        {"name": "chat", "cfg": cfg_small, "batch": 16, "context": 2048,
-         "sla_s": 0.05},
-        {"name": "code", "cfg": cfg_large, "batch": 8, "context": 4096,
-         "sla_s": 0.02},
-    ])
-    total = sum(m.n_chips for m in plan.meshlets)
-    assert total <= 256
-    assert set(plan.assignment) == {"chat", "code"}
-    assert plan.reconfig_cost_s == 0.0  # first configuration is free
-    plan2 = part.plan([{"name": "chat", "cfg": cfg_small, "batch": 16,
-                        "context": 2048, "sla_s": 0.05}])
-    assert plan2.reconfig_cost_s > 0  # repartition pays the MIG-style cost
-
-
-def test_size_for_sla_monotone():
-    part = MeshPartitioner((16, 16))
-    cfg = get_config("phi3-medium-14b")
-    loose = part.size_for_sla(cfg, batch=32, context=8192, sla_s=1.0)
-    tight = part.size_for_sla(cfg, batch=32, context=8192, sla_s=0.005)
-    assert tight >= loose
 
 
 # --- adaptive batching -------------------------------------------------------

@@ -335,6 +335,104 @@ def test_failover_spans_survive_replica_death(granite):
     assert lanes and all(lane.startswith("pool/") for lane in lanes)
 
 
+def test_engine_histograms_agree_with_raw_request_samples(granite):
+    """The engine's bounded TTFT/TPOT/JCT histograms over a seeded Poisson
+    workload hold one sample per request and give p50/p90/p99 within one
+    bucket of the percentiles of the requests' own raw values."""
+    cfg, params = granite
+    rng = np.random.default_rng(0)
+    arrivals = np.cumsum(rng.exponential(1.0 / 0.8, 24))
+    reqs = []
+    for i in range(24):
+        prompt = rng.integers(0, 500, int(rng.integers(8, 33))).astype(np.int32)
+        reqs.append(Request(rid=i, prompt=prompt,
+                            max_new_tokens=int(rng.integers(8, 17)),
+                            arrival_time=float(arrivals[i]),
+                            sampling=_samp(7000 + i)))
+    eng = ServingEngine(cfg, params, EngineConfig(
+        slots=2, window=128, max_seq=192, sync_every=4, tracing=True))
+    pending, now = list(reqs), 0.0
+    while not all(r.done for r in reqs):
+        while pending and pending[0].arrival_time <= now:
+            eng.submit(pending.pop(0), now)
+        eng.step(now)
+        now += 1.0
+        assert now < 2000
+    eng.drain(now)
+    raw = {"ttft": [r.ttft for r in reqs if r.ttft >= 0],
+           "tpot": [r.tpot for r in reqs if r.tpot > 0],
+           "jct": [r.finish_time - r.arrival_time for r in reqs]}
+    hists = {"ttft": eng.metrics.ttfts, "tpot": eng.metrics.tpots,
+             "jct": eng.metrics.jcts}
+    for name, samples in raw.items():
+        h = hists[name]
+        assert h.count == len(samples) == len(reqs), name
+        for q in (50, 90, 99):
+            want = float(np.percentile(samples, q))
+            got = h.percentile(q)
+            assert abs(h.bucket_index(got) - h.bucket_index(want)) <= 1, (
+                name, q, got, want)
+
+
+def test_exported_trace_covers_every_request_through_failover_and_preemption(
+        granite):
+    """One Chrome-trace document exported from a cluster run that loses a
+    replica and from an engine that preempts a request: the document is
+    well formed, every request thread in it shows queued, prefill and
+    decode, and the failover retry and the preempt/restore pair are in it."""
+    cfg, params = granite
+    proxies = [FaultyEngine(ServingEngine(cfg, params, EngineConfig(
+        slots=2, window=64, max_seq=128, sync_every=1, tracing=True)))
+        for _ in range(2)]
+    fe = ClusterFrontend(proxies, policy="round-robin", seed=0,
+                         health_timeout_s=50.0, max_retries=3, tracing=True)
+    failover = [Request(rid=i, prompt=_prompt(10 + i, seed=i),
+                        max_new_tokens=6, sampling=_samp(500 + i))
+                for i in range(6)]
+    for r in failover:
+        fe.submit(r, 0.0)
+    t = 0.0
+    while not all(r.done for r in failover):
+        t += 1.0
+        if t == 2.0:
+            proxies[0].inject("kill")
+        fe.step(t)
+        assert t < 200
+    fe.drain(t)
+
+    eng = ServingEngine(cfg, params, EngineConfig(
+        slots=1, window=64, max_seq=64, sync_every=1, chunk_prefill=0,
+        prefix_cache=True, preemption=True, tracing=True))
+    victim = Request(rid=10, prompt=_prompt(20), max_new_tokens=10,
+                     sampling=_samp(77), ttft_slo_s=100.0)
+    eng.submit(victim, 0.0)
+    for t in (1.0, 2.0, 3.0):
+        eng.step(t)
+    hot = Request(rid=11, prompt=_prompt(10, seed=9), max_new_tokens=3,
+                  priority=1, ttft_slo_s=1.0)
+    eng.submit(hot, 3.0)
+    t = 3.0
+    while not (victim.done and hot.done):
+        t += 1.0
+        eng.step(t)
+        assert t < 200
+    eng.drain(t)
+    assert victim.preemptions >= 1
+
+    doc = chrome_trace(request_traces(failover, prefix="kill/")
+                       + request_traces([victim, hot], prefix="churn/"))
+    assert validate_chrome_trace(doc) == []
+    threads = {}
+    for ev in doc["traceEvents"]:
+        if ev["ph"] in ("X", "i"):
+            threads.setdefault((ev["pid"], ev["tid"]), set()).add(ev["name"])
+    assert len(threads) == len(failover) + 2
+    for key, kinds in threads.items():
+        assert {"queued", "prefill", "decode"} <= kinds, (key, kinds)
+    every = set().union(*threads.values())
+    assert {"failover_retry", "preempt", "restore"} <= every
+
+
 # ---------------------------------------------------------------------------
 # compile accounting + profiler hook + registries
 # ---------------------------------------------------------------------------

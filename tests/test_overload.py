@@ -345,8 +345,8 @@ def _drive(fe, reqs, *, max_steps=600):
 
 def test_cluster_ladder_sheds_low_tier_protects_top(granite):
     # backlog_high_s is on the engine cost-model scale (ticks estimate in
-    # milliseconds of virtual compute), not the 1s driver cadence — same
-    # derivation as benchmarks/overload_bench.py.
+    # milliseconds of virtual compute), not the 1s driver cadence: a few
+    # modeled requests of backlog per replica trip the ladder.
     cfg, params = granite
     det = OverloadDetector(ttft_slo_s=8.0, backlog_high_s=0.002,
                            period_s=1.0, patience=1, relax_patience=50)
@@ -507,3 +507,61 @@ def test_config_validates_trace_knobs():
         EngineConfig(trace_sample_n=0)
     with pytest.raises(ValueError):
         EngineConfig(trace_ring=-1)
+
+
+def test_overload_stack_streams_match_unloaded_reference(granite):
+    """A bulk flood at several times the cluster's drain rate, beside steady
+    gold and silver traffic, through the whole overload stack (tenants,
+    detector, paced fair queue, token bucket): every finished stream equals
+    the one an unloaded engine serves for that request, a browned-out
+    stream is an exact prefix of it, and every request the ladder turned
+    away carries a finite retry-after."""
+    cfg, params = granite
+    rng = np.random.default_rng(0)
+    reqs, rid = [], 0
+    for tenant, n, t0, gap, lo, hi, slo in (
+            ("gold", 10, 2.0, 6.0, 8, 17, 10.0),
+            ("silver", 6, 4.0, 9.0, 8, 17, 20.0),
+            ("bulk", 36, 10.0, 0.5, 12, 25, 0.0)):
+        for i in range(n):
+            plen = int(rng.integers(lo, hi))
+            budget = int(rng.integers(8, 13) if tenant != "bulk"
+                         else rng.integers(10, 17))
+            reqs.append(Request(rid, rng.integers(0, 500, plen)
+                                .astype(np.int32), budget,
+                                arrival_time=t0 + gap * i, tenant=tenant,
+                                ttft_slo_s=slo))
+            rid += 1
+    ref_eng = ServingEngine(cfg, params, EngineConfig(
+        slots=2, window=128, max_seq=192, sync_every=4))
+    ref = [Request(r.rid, r.prompt.copy(), r.max_new_tokens) for r in reqs]
+    _drive(ref_eng, ref)
+    want = {r.rid: list(r.output) for r in ref}
+
+    det = OverloadDetector(ttft_slo_s=10.0, backlog_high_s=0.002,
+                           period_s=1.0, patience=1, relax_patience=6)
+    tenants = {
+        "gold": TenantClass("gold", tier=2, weight=4.0),
+        "silver": TenantClass("silver", tier=1, weight=2.0,
+                              brownout_frac=0.5),
+        "bulk": TenantClass("bulk", tier=0, weight=1.0, rate_tokens_s=256.0,
+                            burst_tokens=2048.0),
+    }
+    engines = [ServingEngine(cfg, params, EngineConfig(
+        slots=2, window=128, max_seq=192, sync_every=4)) for _ in range(2)]
+    fe = ClusterFrontend(engines, policy="predicted", seed=0,
+                         tenants=tenants, overload=det, fair_quantum=64.0)
+    resolved = _drive(fe, reqs)
+    finished = [r for r in resolved.values()
+                if r.state is RequestState.FINISHED]
+    turned_away = [r for r in resolved.values()
+                   if r.state is not RequestState.FINISHED]
+    assert turned_away and any(r.browned_out_tokens for r in finished)
+    for r in finished:
+        got = list(r.output)
+        if r.browned_out_tokens:
+            assert got == want[r.rid][:len(got)], r.rid
+        else:
+            assert got == want[r.rid], r.rid
+    for r in turned_away:
+        assert 0 < r.retry_after_s < float("inf"), (r.rid, r.fail_reason)

@@ -9,8 +9,6 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.costmodel import WorkEstimate, estimate_decode
 from repro.core.misd.interference import progress_rates
-from repro.core.misd.scheduler import Device, FIFOScheduler, Job, MISDSimulator
-from repro.core.simd.offload import zipf_hit_rate
 from repro.models.layers import block_attention, dense_attention
 
 demand = st.tuples(st.floats(0.01, 1.0), st.floats(0.01, 1.0))
@@ -26,30 +24,6 @@ def test_progress_rates_valid(demands):
     if len(demands) > 1:
         fewer = progress_rates(demands[:-1])
         assert all(a <= b + 1e-12 for a, b in zip(rates, fewer))
-
-
-@given(st.lists(st.tuples(st.floats(0.001, 0.1), st.floats(0.0, 0.5)),
-                min_size=1, max_size=30))
-@settings(max_examples=30, deadline=None)
-def test_simulator_conservation(specs):
-    """Every job completes exactly once, never before arrival+service."""
-    jobs = [Job(i, "m", (0.5, 0.5), s, arrival=a)
-            for i, (s, a) in enumerate(specs)]
-    res = MISDSimulator([Device("d", 3)], FIFOScheduler()).run(jobs)
-    assert len(res.completed) == len(specs)
-    ids = sorted(j.jid for j in res.completed)
-    assert ids == list(range(len(specs)))
-    for j in res.completed:
-        assert j.finish >= j.arrival + j.service_s - 1e-9
-
-
-@given(st.integers(1, 1000), st.integers(1, 1000))
-@settings(max_examples=50, deadline=None)
-def test_zipf_hit_rate_bounds(cache, total):
-    h = zipf_hit_rate(cache, total)
-    assert 0.0 <= h <= 1.0
-    if cache >= total:
-        assert h == 1.0
 
 
 @given(st.integers(1, 256), st.integers(128, 8192))

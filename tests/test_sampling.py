@@ -130,6 +130,38 @@ def test_sampled_streams_reproducible_across_cache_layout_and_fusion(granite):
     assert base == rolling == unfused
 
 
+def test_sampled_streams_identical_after_engine_reset(granite):
+    """A mixed greedy/sampled workload over three prompt buckets, replayed
+    on the same engine after ``reset()`` (warm jit caches, every slot, queue
+    and counter cleared), reproduces every stream of the first run and of a
+    fresh engine, and compiles nothing new."""
+    cfg, params = granite
+
+    def workload():
+        return [Request(rid=rid, prompt=_prompt((9, 14, 21, 33)[rid % 4],
+                                                seed=rid),
+                        max_new_tokens=8,
+                        sampling=(SamplingParams(temperature=0.8, top_k=40,
+                                                 top_p=0.95, seed=500 + rid)
+                                  if rid % 2 else SamplingParams()))
+                for rid in range(6)]
+
+    def serve(eng):
+        reqs = workload()
+        _serve(eng, reqs)
+        return {r.rid: r.output for r in reqs}
+
+    kw = dict(slots=3, window=128, sync_every=4)
+    eng = ServingEngine(cfg, params, EngineConfig(**kw))
+    first = serve(eng)
+    traces = (eng.prefill_traces, eng.decode_traces)
+    assert traces[0] <= 3 and traces[1] <= 2  # a trace per bucket; tick+scan
+    eng.reset()
+    assert serve(eng) == first
+    assert (eng.prefill_traces, eng.decode_traces) == traces
+    assert serve(ServingEngine(cfg, params, EngineConfig(**kw))) == first
+
+
 def test_seed_changes_the_stream(granite):
     cfg, params = granite
     a, _ = _streams(cfg, params, [0], sampling=SamplingParams(

@@ -239,6 +239,35 @@ def test_deferred_sync_matches_per_tick(granite):
     assert engines[8].metrics.host_syncs < engines[1].metrics.host_syncs
 
 
+def test_mixed_bucket_workload_keeps_trace_and_sync_budget(granite):
+    """Six prompts over two power-of-two buckets, served through slot churn
+    on three slots: one prefill trace per bucket, at most two decode traces
+    (single tick + fused scan), and at most one host sync per ``sync_every``
+    decode ticks plus one per request (its admission) and one to drain."""
+    cfg, params = granite
+    sync_every = 4
+    eng = make_engine(cfg, params, slots=3, window=128,
+                      sync_every=sync_every, chunk_prefill=0)
+    reqs = [make_request(i, _prompt(plen, seed=i), max_new_tokens=9)
+            for i, plen in enumerate((9, 12, 15, 17, 21, 31))]
+    for r in reqs:
+        eng.submit(r, 0.0)
+    t = 0.0
+    while not all(r.done for r in reqs):
+        t += 1.0
+        eng.step(t)
+    eng.drain(t)
+    m = eng.metrics
+    assert eng.prefill_traces == 2
+    assert eng.decode_traces <= 2
+    assert m.host_syncs <= m.decode_ticks / sync_every + len(reqs) + 1
+    assert m.completed == len(reqs)
+    assert all(len(r.output) == 9 for r in reqs)
+    if eng.paged:  # every page back, but those the prefix index holds
+        held = eng.prefix_index.cached_pages if eng.prefix_index else 0
+        assert eng.allocator.pages_in_use == held
+
+
 def test_mrope_decode_on_device(granite):
     """The mrope decode path builds positions from the cache's pos leaf on
     device (no per-tick host round-trip) and still decodes correctly."""

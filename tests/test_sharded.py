@@ -4,13 +4,22 @@ streams (the exact GSPMD profile all-gathers activations instead of
 psum-reducing partial products), flat trace counts, and the same page
 accounting under preemption churn.
 
-These tests need 8 XLA devices. The CI shard8 matrix cell provides them
-(REPRO_ENGINE_TOPOLOGY=tp8 makes conftest inject
-``--xla_force_host_platform_device_count=8`` before jax initializes);
-on a plain host they skip. Engines are built directly from pinned
-``EngineConfig``s — each test needs a tp=1 and a tp=8 engine side by
-side, so the matrix cell's topology override must not apply."""
+These tests need 8 XLA devices. Where the process has them (the CI
+shard8 matrix cell, whose conftest forces 8 host devices before jax
+starts) they run here. Elsewhere this file runs once more in a child
+pytest with ``--xla_force_host_platform_device_count=8`` added to
+``XLA_FLAGS`` on the CPU, and each test here passes or fails as it did in
+that child, printing the child's failure and output. Engines are built
+directly from pinned ``EngineConfig``s — each test needs a tp=1 and a
+tp=8 engine side by side, so the matrix cell's topology override must
+not apply."""
 import dataclasses
+import functools
+import inspect
+import os
+import subprocess
+import sys
+from xml.etree import ElementTree
 
 import jax
 import numpy as np
@@ -27,11 +36,64 @@ from repro.serving import (
 )
 
 NDEV = 8
-pytestmark = pytest.mark.skipif(
-    jax.local_device_count() < NDEV,
-    reason=f"needs {NDEV} XLA devices (run under "
-           f"XLA_FLAGS=--xla_force_host_platform_device_count={NDEV} "
-           f"or the shard8 CI cell)")
+FORCE = f"--xla_force_host_platform_device_count={NDEV}"
+IN_PROCESS = jax.local_device_count() >= NDEV
+
+
+@pytest.fixture(scope="module")
+def child_run(tmp_path_factory):
+    """Runs this file once in a child pytest on NDEV forced CPU devices.
+    Returns a function from a test's name to why it failed there ('' when
+    it passed)."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    assert FORCE not in flags, (
+        f"{FORCE} gave {jax.local_device_count()} devices, not {NDEV}")
+    report = tmp_path_factory.mktemp("shard8") / "junit.xml"
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS=f"{flags} {FORCE}".strip())
+    child = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         f"--junitxml={report}", os.path.abspath(__file__)],
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        env=env, capture_output=True, text=True, timeout=900)
+    output = (child.stdout + child.stderr)[-8000:]
+    cases = {}
+    if report.exists():
+        for case in ElementTree.parse(report).iter("testcase"):
+            cases[case.get("name")] = "\n".join(
+                f"{e.tag}: {e.get('message', '')}\n{e.text or ''}"
+                for e in case if e.tag in ("failure", "error", "skipped"))
+
+    def failure(name):
+        if name not in cases:
+            return (f"{name}: no result from the {NDEV}-device child "
+                    f"(exit {child.returncode})\n{output}")
+        if cases[name]:
+            return (f"{name} failed on {NDEV} devices in the child:\n"
+                    f"{cases[name]}\n--- child output ---\n{output}")
+        return ""
+
+    return failure
+
+
+def on_ndev(test):
+    """``test`` itself where this process has NDEV devices. Elsewhere a
+    stand-in with the same name, marks and parameters that passes or fails
+    as ``test`` did in the child run."""
+    if IN_PROCESS:
+        return test
+
+    @functools.wraps(test)
+    def judged(request, **_):
+        why = request.getfixturevalue("child_run")(request.node.name)
+        if why:
+            pytest.fail(why, pytrace=False)
+
+    sig = inspect.signature(test)
+    judged.__signature__ = sig.replace(parameters=[
+        inspect.Parameter("request", inspect.Parameter.POSITIONAL_OR_KEYWORD),
+        *sig.parameters.values()])
+    return judged
 
 
 @pytest.fixture(scope="module")
@@ -89,6 +151,7 @@ def _pair(cfg, params, **kw):
 # ---------------------------------------------------------------------------
 
 
+@on_ndev
 @pytest.mark.parametrize("kw", [dict(paged=True), dict(prefix_cache=True)],
                          ids=["paged", "prefix_cache"])
 def test_sharded_streams_bit_identical(dense, kw):
@@ -100,6 +163,7 @@ def test_sharded_streams_bit_identical(dense, kw):
     assert sb == ss  # not close — EQUAL, token for token
 
 
+@on_ndev
 def test_sharded_trace_parity(dense):
     """Tensor parallelism must not multiply compiles: the sharded engine
     reuses one prefill and one decode trace exactly like 1-chip."""
@@ -111,6 +175,7 @@ def test_sharded_trace_parity(dense):
         == (base.prefill_traces, base.decode_traces)
 
 
+@on_ndev
 def test_sharded_moe_expert_parallel_bit_identical(moe):
     """Expert-parallel MoE decode under the strict capacity policy (the
     sharded-MoE default): the expert all-to-all must not perturb a single
@@ -123,6 +188,7 @@ def test_sharded_moe_expert_parallel_bit_identical(moe):
     assert sb == ss
 
 
+@on_ndev
 def test_sharded_moe_strict_is_default(moe):
     cfg, params = moe
     eng = ServingEngine(cfg, params, EngineConfig(
@@ -135,6 +201,7 @@ def test_sharded_moe_strict_is_default(moe):
 # ---------------------------------------------------------------------------
 
 
+@on_ndev
 def test_sharded_preempt_restore_exact_and_pages_drain(dense):
     """Host-side page tables are layout-identical under sharding, so the
     preempt/restore machinery must work unchanged: the restored stream is
@@ -175,6 +242,7 @@ def test_sharded_preempt_restore_exact_and_pages_drain(dense):
 # ---------------------------------------------------------------------------
 
 
+@on_ndev
 def test_sharded_load_report_axis_fields(dense):
     cfg, params = dense
     _, shard = _pair(cfg, params)

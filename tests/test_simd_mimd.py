@@ -1,25 +1,14 @@
-"""SIMD + MIMD quadrants: sharding specs, DLRM distributed embedding,
-heterogeneous-memory offload, service router."""
+"""SIMD + MIMD quadrants: sharding specs and the service router."""
 import dataclasses
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ASSIGNED_ARCHS, get_config
-from repro.configs.dlrm import CONFIG as DLRM_CFG
 from repro.core.misd.scheduler import Device, Job
 from repro.core.mimd import Instance, ServiceRouter
-from repro.core.simd import (
-    dlrm_forward,
-    init_dlrm,
-    lookup_traffic_bytes,
-    plan_offload,
-    shard_specs,
-    zipf_hit_rate,
-)
 from repro.core.simd.sharding import (
     batch_pspecs,
     cache_pspecs,
@@ -90,63 +79,6 @@ def test_cache_specs_shard_every_kv_leaf():
     kv = [s for p, s in flat if str(p[-1]) in ("['k']", "['v']") or
           getattr(p[-1], "key", "") in ("k", "v")]
     assert kv and all("model" in [e for e in s if e] for s in kv)
-
-
-# --- DLRM (survey Fig. 7) ----------------------------------------------------
-
-
-def _tiny_dlrm():
-    return dataclasses.replace(
-        DLRM_CFG, num_tables=4, rows_per_table=64, embed_dim=16,
-        bottom_mlp=(32, 16), top_mlp=(32, 1))
-
-
-def test_dlrm_forward_shape_and_grad():
-    cfg = _tiny_dlrm()
-    params = init_dlrm(cfg, jax.random.key(0))
-    b = 8
-    batch = {
-        "dense": jnp.ones((b, cfg.num_dense_features)),
-        "sparse": jnp.zeros((b, cfg.num_tables, cfg.multi_hot), jnp.int32),
-    }
-    out = dlrm_forward(cfg, params, batch)
-    assert out.shape == (b,)
-    assert not jnp.isnan(out).any()
-
-
-def test_dlrm_embedding_dominates():
-    """Survey: embedding tables are 80–95%+ of DLRM weights."""
-    frac = DLRM_CFG.embedding_params() / DLRM_CFG.param_count()
-    assert frac > 0.8
-
-
-def test_dlrm_lookup_traffic_scales_with_batch():
-    assert lookup_traffic_bytes(DLRM_CFG, 64) == 2 * lookup_traffic_bytes(
-        DLRM_CFG, 32)
-
-
-def test_dlrm_shard_specs_cover_tables():
-    specs = shard_specs(DLRM_CFG)
-    assert specs["tables"] == P(None, "model", None)
-
-
-# --- heterogeneous memory (survey §4.3.2) ------------------------------------
-
-
-def test_zipf_hit_rate_monotone():
-    hs = [zipf_hit_rate(int(f * 1e6), int(1e6)) for f in (0.01, 0.1, 0.5, 1.0)]
-    assert all(a < b or b == 1.0 for a, b in zip(hs, hs[1:]))
-    assert hs[-1] == 1.0
-
-
-def test_offload_near_hbm_with_small_hot_set():
-    """[47][49]: a small HBM cache over Zipf accesses ~ on-par with DRAM."""
-    rows, row_bytes = 10_000_000, 512
-    plan = plan_offload(rows, row_bytes, hbm_budget_bytes=0.2 * rows * row_bytes)
-    assert plan.hit_rate > 0.6
-    assert plan.slowdown_vs_hbm < 12  # vs 25x raw HBM/PCIe gap
-    none = plan_offload(rows, row_bytes, hbm_budget_bytes=0)
-    assert none.slowdown_vs_hbm > plan.slowdown_vs_hbm
 
 
 # --- MIMD router -------------------------------------------------------------

@@ -1,34 +1,8 @@
-"""Tooling correctness: the dry-run's HLO collective parser, the
-affine-probe extrapolation, data-pipeline determinism, batching runtime."""
+"""Tooling correctness: data-pipeline determinism, batching runtime."""
 import numpy as np
 
-from repro.launch import dryrun  # safe: only sets XLA_FLAGS in its process
 from repro.core.misd.batching import BatchAccumulator
 from repro.training.data import TokenPipeline
-
-
-def test_collective_parser_counts_and_multiplies():
-    hlo = """
-  %ag = bf16[4,1024]{1,0} all-gather(%x), replica_groups={}
-  %ar = f32[8,256]{1,0} all-reduce(%y), to_apply=%sum
-  %a2a.1 = (f32[2,2]{1,0}, f32[2,2]{1,0}) all-to-all(%a, %b)
-  %cp = u8[16]{0} collective-permute(%z)
-  %ags = bf16[4,4]{1,0} all-gather-start(%w)
-"""
-    got = dryrun.collective_bytes(hlo)
-    assert got["all-gather"] == 4 * 1024 * 2 + 4 * 4 * 2  # incl. -start
-    assert got["all-reduce"] == 8 * 256 * 4 * 2.0  # ring multiplier
-    assert got["all-to-all"] == 2 * (2 * 2 * 4)
-    assert got["collective-permute"] == 16
-
-
-def test_affine_probe_extrapolation_exact():
-    """cost = a*r + b is recovered exactly from two probes."""
-    a, b = 3.5e12, 1.1e11
-    r1, r2, target = 2, 4, 40
-    v1, v2 = a * r1 + b, a * r2 + b
-    slope = (v2 - v1) / (r2 - r1)
-    assert abs((v2 + slope * (target - r2)) - (a * target + b)) < 1e-3
 
 
 def test_token_pipeline_deterministic_and_seekable():
